@@ -26,6 +26,9 @@ SECTIONS = {
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     names = sys.argv[1:] or list(SECTIONS)
     print("name,us_per_call,derived")
     for key in names:

@@ -123,23 +123,32 @@ def _bfs_reachable(neighbors: np.ndarray, start: int) -> np.ndarray:
 
 
 def _add_reverse_edges(nbr: np.ndarray, deg: np.ndarray, M: int) -> None:
-    """Line 14: add (v, u) for every (u, v), respecting the degree cap."""
+    """Line 14: add (v, u) for every (u, v), respecting the degree cap.
+
+    Vectorized form of the sequential rule: visit edges grouped by
+    destination u (ascending source within a group) and append source v to
+    N(u) unless v == u, v is already in N(u), or N(u) is full."""
     n = nbr.shape[0]
-    src = np.repeat(np.arange(n, dtype=np.int32), nbr.shape[1])
-    dst = nbr.ravel()
+    src = np.repeat(np.arange(n, dtype=np.int64), nbr.shape[1])
+    dst = nbr.ravel().astype(np.int64)
     ok = dst >= 0
     src, dst = src[ok], dst[ok]
-    # iterate edges grouped by destination; numpy-side, O(E)
     order = np.argsort(dst, kind="stable")
-    src, dst = src[order], dst[order]
-    for u, v in zip(dst.tolist(), src.tolist()):  # add v into N(u)
-        if deg[u] >= M:
-            continue
-        row = nbr[u, : deg[u]]
-        if v == u or (row == v).any():
-            continue
-        nbr[u, deg[u]] = v
-        deg[u] += 1
+    u, v = dst[order], src[order]                 # add v into N(u)
+    key = u * n + v
+    # v already in N(u) ⇔ the edge (u, v) exists; a repeated (u, v) pair
+    # counts once (its first visit)
+    present = np.isin(key, src * n + dst)
+    first = np.zeros(key.size, bool)
+    first[np.unique(key, return_index=True)[1]] = True
+    cand = (u != v) & ~present & first
+    # rank of each candidate within its destination group
+    csum = np.cumsum(cand)
+    group_start = np.searchsorted(u, u, side="left")
+    rank = csum - 1 - (csum[group_start] - cand[group_start])
+    take = cand & (rank < M - deg[u])
+    nbr[u[take], deg[u[take]] + rank[take]] = v[take]
+    deg += np.bincount(u[take], minlength=n).astype(deg.dtype)
 
 
 def _repair_connectivity(vectors_np: np.ndarray, nbr: np.ndarray,
@@ -175,15 +184,17 @@ def _repair_connectivity(vectors_np: np.ndarray, nbr: np.ndarray,
 
 def _candidate_search(graph: GraphIndex, queries: jax.Array, L: int,
                       max_hops: int):
-    """Line 6: R_u ← GreedySearch(G, v_s, u, L, L), returning candidates."""
+    """Line 6: R_u ← GreedySearch(G, v_s, u, L, L), returning candidates
+    and each query's hop count."""
     p = SearchParams(k=min(L, graph.n), l0=L, l_max=L, adaptive=False,
                      max_hops=max_hops)
-    _, cand_ids, cand_dists = search(graph, queries, p, with_candidates=True)
-    return cand_ids, cand_dists
+    res, cand_ids, cand_dists = search(graph, queries, p, with_candidates=True)
+    return cand_ids, cand_dists, res.n_hops
 
 
 def _reverse_lists(nbr: np.ndarray, cap: int) -> np.ndarray:
-    """int32[n, cap] of reverse neighbors (nodes pointing at each row)."""
+    """int32[n, cap] of reverse neighbors (nodes pointing at each row), the
+    first ``cap`` sources of each row in ascending order."""
     n, M = nbr.shape
     src = np.repeat(np.arange(n, dtype=np.int32), M)
     dst = nbr.ravel()
@@ -191,14 +202,10 @@ def _reverse_lists(nbr: np.ndarray, cap: int) -> np.ndarray:
     src, dst = src[ok], dst[ok]
     order = np.argsort(dst, kind="stable")
     src, dst = src[order], dst[order]
+    rank = np.arange(dst.size) - np.searchsorted(dst, dst, side="left")
+    keep = rank < cap
     out = np.full((n, cap), -1, np.int32)
-    counts = np.zeros(n, np.int32)
-    starts = np.searchsorted(dst, np.arange(n))
-    ends = np.searchsorted(dst, np.arange(n) + 1)
-    for u in range(n):
-        take = src[starts[u] : ends[u]][:cap]
-        out[u, : take.size] = take
-        counts[u] = take.size
+    out[dst[keep], rank[keep]] = src[keep]
     return out
 
 
@@ -266,20 +273,26 @@ def _align_degrees(vectors, nbr, deg, cand_ids_all, cand_dists_all, p: BuildPara
             rule=p.rule, max_keep=M,
         )
         kept, cnt = np.array(kept), np.array(cnt)
-        # pad any still-deficient rows with nearest unselected candidates
-        ids_np = cand_ids_all[idx]
-        for j in range(idx.size):
-            row = kept[j]
-            c = int(cnt[j])
-            if c < M:
-                pool = ids_np[j]
-                pool = pool[(pool >= 0) & (pool != idx[j])]
-                extra = [x for x in pool.tolist() if x not in set(row[:c].tolist())]
-                take = extra[: M - c]
-                row[c : c + len(take)] = take
-                cnt[j] = c + len(take)
-            nbr[idx[j]] = row
-            deg[idx[j]] = cnt[j]
+        _pad_from_pool(kept, cnt, cand_ids_all[idx], idx)
+        nbr[idx] = kept
+        deg[idx] = cnt
+
+
+def _pad_from_pool(kept: np.ndarray, cnt: np.ndarray, pool: np.ndarray,
+                   self_ids: np.ndarray) -> None:
+    """Fill each row's free slots (``kept[j, cnt[j]:]``) with its nearest
+    unselected candidates, in candidate order; ``pool`` rows are unique ids
+    (-1 = empty).  In place on ``kept`` and ``cnt``."""
+    M = kept.shape[1]
+    kept_mask = np.arange(M)[None, :] < cnt[:, None]
+    chosen = ((pool[:, :, None] == kept[:, None, :])
+              & kept_mask[:, None, :]).any(axis=2)
+    extra = (pool >= 0) & (pool != self_ids[:, None]) & ~chosen
+    rank = np.cumsum(extra, axis=1) - 1
+    take = extra & (rank < (M - cnt)[:, None])
+    rows, cols = np.nonzero(take)
+    kept[rows, cnt[rows] + rank[rows, cols]] = pool[rows, cols]
+    cnt += take.sum(axis=1).astype(cnt.dtype)
 
 
 def build_approx(vectors, params: BuildParams = BuildParams(),
@@ -316,6 +329,12 @@ def build_approx(vectors, params: BuildParams = BuildParams(),
 
     for it in range(p.iters):
         t0 = time.perf_counter()
+        # where the iteration's time goes: candidate search (device, synced
+        # by the host copy that follows it), neighbor selection, and the
+        # graph surgery on the host; block_hops is each block's lock-step
+        # loop length (the slowest query of the block)
+        search_s = select_s = 0.0
+        block_hops = []
         new_nbr = np.full((n, M), -1, np.int32)
         new_deg = np.zeros(n, np.int32)
         # candidate enrichment: beam-search candidates ∪ current out-neighbors
@@ -328,12 +347,16 @@ def build_approx(vectors, params: BuildParams = BuildParams(),
         for s in range(0, n, p.block):
             ids_blk = np.arange(s, min(s + p.block, n), dtype=np.int32)
             q_blk = jnp.asarray(vectors_np[ids_blk])
-            cand_ids, cand_dists = _candidate_search(graph, q_blk, L, p.max_hops)
+            ts = time.perf_counter()
+            cand_ids, cand_dists, hops = _candidate_search(
+                graph, q_blk, L, p.max_hops)
+            cand_np = np.asarray(cand_ids)
+            block_hops.append(int(np.max(np.asarray(hops))))
+            search_s += time.perf_counter() - ts
             merged = np.concatenate(
-                [np.asarray(cand_ids), cur_nbr[ids_blk], rev_nbr[ids_blk]],
-                axis=1,
-            )
+                [cand_np, cur_nbr[ids_blk], rev_nbr[ids_blk]], axis=1)
             merged = _dedup_rows(merged, ids_blk)
+            ts = time.perf_counter()
             cand_ids, cand_dists = _prep_candidates(
                 vectors, jnp.asarray(ids_blk), jnp.asarray(merged), L)
             kept, cnt = _select_block(
@@ -343,12 +366,15 @@ def build_approx(vectors, params: BuildParams = BuildParams(),
             )
             new_nbr[ids_blk] = np.asarray(kept)
             new_deg[ids_blk] = np.asarray(cnt)
+            select_s += time.perf_counter() - ts
             if it == p.iters - 1:
                 cand_ids_all[ids_blk] = np.asarray(cand_ids)
                 cand_dists_all[ids_blk] = np.asarray(cand_dists)
 
+        ts = time.perf_counter()
         _add_reverse_edges(new_nbr, new_deg, M)
         n_fixed = _repair_connectivity(vectors_np, new_nbr, new_deg, M, med)
+        surgery_s = time.perf_counter() - ts
         graph = GraphIndex(vectors, jnp.asarray(new_nbr), jnp.int32(med),
                            kind="delta_emg_approx", delta=p.delta or 0.0)
         if p.checkpoint_dir:
@@ -359,7 +385,10 @@ def build_approx(vectors, params: BuildParams = BuildParams(),
         _build_event(metrics, verbose, f"refine_iter{it}", nodes=n,
                      elapsed_s=elapsed, nodes_per_s=n / max(elapsed, 1e-9),
                      mean_deg=float((new_nbr >= 0).sum(1).mean()),
-                     repaired=n_fixed)
+                     repaired=n_fixed, search_s=search_s, select_s=select_s,
+                     surgery_s=surgery_s,
+                     block_hops_mean=float(np.mean(block_hops)),
+                     block_hops_max=max(block_hops))
 
     if p.align_degree:
         t0 = time.perf_counter()

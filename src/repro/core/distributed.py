@@ -49,14 +49,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
-
-if hasattr(jax, "shard_map"):                      # jax ≥ 0.6
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .build_approx import BuildParams, build_approx
 from .emqg import build_emqg
@@ -117,6 +110,25 @@ def stack_indices(indices: Sequence, offsets: Sequence[int], n_total: int,
                         sizes=jnp.asarray(sizes, jnp.int32))
 
 
+def slot_sharding(mesh, shard_axes=("data",)) -> NamedSharding:
+    """``NamedSharding(mesh, P(shard_axes))`` on an Auto-typed view of
+    ``mesh``: the slot layout stays a placement and never enters the
+    arrays' types (on an Explicit mesh, as ``jax.make_mesh`` builds, every
+    later op on one slot would need a mesh context)."""
+    auto = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    return NamedSharding(auto, P(shard_axes))
+
+
+def place_sharded(sidx: ShardedIndex, mesh,
+                  shard_axes=("data",)) -> ShardedIndex:
+    """Commit every leaf of ``sidx`` to ``slot_sharding(mesh, shard_axes)``
+    — the layout the sharded search's ``shard_map`` reads, so no batch
+    reshards the index.  A no-op for leaves already placed so."""
+    sharding = slot_sharding(mesh, shard_axes)
+    return jax.tree.map(lambda x: jax.device_put(x, sharding), sidx)
+
+
 def shard_rows(vectors: np.ndarray, shard: int, per: int) -> tuple[np.ndarray, int]:
     """Rows of contiguous shard ``shard`` (capacity ``per``), padded to
     ``per`` by wrapping the shard's first row (or global row 0 when the shard
@@ -150,7 +162,9 @@ def build_shard(rows: np.ndarray, shard: int,
 def build_sharded(vectors, n_shards: int, params: Optional[BuildParams] = None,
                   quantized: bool = False, seed: int = 0) -> ShardedIndex:
     """Contiguous row partition; per-shard Algorithm-4 builds (equal-sized,
-    last shard padded by wrapping)."""
+    last shard padded by wrapping).  Every shard is built on the default
+    device, so all shards run the same compiled build programs; spread the
+    result over a mesh with ``place_sharded``."""
     vectors = np.asarray(vectors, np.float32)
     n = vectors.shape[0]
     per = int(np.ceil(n / n_shards))
@@ -210,7 +224,8 @@ def make_sharded_search(mesh, shard_axes=("data",), query_axis=None,
     — e.g. index over 'data', queries over ('pod','model').
     Returns fn(sharded_index, queries [B, d], params) → (ids, dists) [B, k]
     with outputs replicated over ``shard_axes`` and sharded over
-    ``query_axis``.  The ring merge needs a single shard axis (ppermute is
+    ``query_axis``; fn is jitted (``params`` static), so a batch of a shape
+    already served runs without retracing.  The ring merge needs a single shard axis (ppermute is
     defined on one mesh axis); multi-axis shards use all_gather.
     """
     axis_name = shard_axes if len(shard_axes) > 1 else shard_axes[0]
@@ -241,6 +256,7 @@ def make_sharded_search(mesh, shard_axes=("data",), query_axis=None,
             mi, md = _merge_all_gather(gids, d, params.k, axis_name)
         return jnp.where(jnp.isfinite(md), mi, -1), md
 
+    @partial(jax.jit, static_argnames=("params",))
     def run(sidx: ShardedIndex, queries, params: SearchParams, valid=None):
         if valid is None:
             valid = jnp.ones((n_shards,), bool)
@@ -252,12 +268,12 @@ def make_sharded_search(mesh, shard_axes=("data",), query_axis=None,
             q_spec,
             P(shard_axes),
         )
-        fn = _shard_map(
+        fn = jax.shard_map(
             partial(body, params=params),
             mesh=mesh,
             in_specs=in_specs,
             out_specs=(q_spec, q_spec),
-            **{_CHECK_KW: False},
+            check_vma=False,
         )
         return fn(sidx, queries, jnp.asarray(valid, bool))
 
@@ -462,7 +478,7 @@ class FaultTolerantShardedSearch:
         if n_slots % n_replicas:
             raise ValueError(f"{n_slots} slots not divisible by "
                              f"{n_replicas} replicas")
-        self.sidx = sidx
+        self.sidx = place_sharded(sidx, mesh, shard_axes)
         self.quantized = quantized
         # a shared registry lets several searchers (e.g. the two merge
         # strategies of a resilient server) see one liveness truth
@@ -479,6 +495,12 @@ class FaultTolerantShardedSearch:
             offs = np.asarray(sidx.offsets)[::n_replicas]
             self.shard_sizes = np.diff(
                 np.append(offs, sidx.n_total)).astype(int)
+
+    def lower(self, queries, params: SearchParams):
+        """``jax.stages.Lowered`` of the masked sharded search for this
+        batch shape (compile it to check the program without running)."""
+        return self._run.lower(self.sidx, queries, params,
+                               valid=self.registry.participation())
 
     def __call__(self, queries, params: SearchParams) -> ShardedSearchResult:
         mask = self.registry.participation()
@@ -506,8 +528,10 @@ def host_reference_merge(sidx: ShardedIndex, registry: ShardHealthRegistry,
     and audit use only."""
     mask = registry.participation()
     all_i, all_d = [], []
+    dev = jax.devices()[0]          # one device: one compile for every slot
     for slot in np.where(mask)[0]:
-        local = jax.tree.map(lambda x, s=slot: x[s], sidx.index)
+        local = jax.tree.map(lambda x, s=slot: jax.device_put(x[s], dev),
+                             sidx.index)
         res = _local_search(local, queries, params, quantized)
         ids = np.asarray(res.ids)
         offs = int(np.asarray(sidx.offsets)[slot])

@@ -33,7 +33,8 @@ def _unpack_tile(codes):
     TM, W = codes.shape
     shifts = jax.lax.broadcasted_iota(jnp.uint32, (TM, W, 32), 2)
     bits = (codes[:, :, None] >> shifts) & jnp.uint32(1)
-    return bits.reshape(TM, W * 32).astype(jnp.float32)
+    # Mosaic has no uint32 → f32 cast; the bits are 0/1, so int32 is exact.
+    return bits.reshape(TM, W * 32).astype(jnp.int32).astype(jnp.float32)
 
 
 def _bitdot_kernel(q_ref, codes_ref, out_ref):

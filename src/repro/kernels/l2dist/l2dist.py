@@ -27,10 +27,15 @@ Two kernels, matching the two halves of a graph-search expansion:
                   tile before a single vectorized (R, d) distance reduction —
                   R in-flight copies amortize DMA issue latency and the
                   compute runs on a full tile instead of one row.  VMEM per
-                  step is R·d·4 B (8×128 → 4 KiB) plus the (1, d) query line.
+                  step is R·d·4 B (8×128 → 4 KiB) plus the (1, d) query line;
+                  the step's R row ids are blocked into SMEM.
 
-Validated on CPU in interpret mode against ``ref.py``; compiled path is
-exercised structurally by the dry-run.
+``gather_l2_tiled`` and the bitdot kernels compile for a TPU v5e
+(``tests/test_tpu_compile.py``).  ``batched_l2`` and ``gather_l2`` do not:
+their (1, d) row blocks break the TPU tiling rule (the last two block dims
+must divide (8, 128) or equal the array's), and nothing on the serve path
+calls them.  All four are validated on CPU in interpret mode against
+``ref.py``.
 """
 
 from __future__ import annotations
@@ -107,67 +112,64 @@ def gather_l2_pallas(base: jax.Array, ids: jax.Array, queries: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# gather_l2_tiled: base [n, d] + ids [B, M] + queries [B, d] → d2 [B, M],
+# gather_l2_tiled: base [n, d] + ids [B, K] + queries [B, d] → d2 [B, K],
 # R = block_rows gathered rows per grid step.
 # ---------------------------------------------------------------------------
 
 def _gather_l2_tiled_kernel(ids_ref, base_hbm, q_ref, out_ref, rows_vmem,
-                            sems, *, block_rows: int):
-    b = pl.program_id(0)
-    t = pl.program_id(1)
+                            sem, *, block_rows: int):
     R = block_rows
 
     def row_dma(r):
-        row = ids_ref[b, t * R + r]
         return pltpu.make_async_copy(
-            base_hbm.at[pl.ds(row, 1), :],
+            base_hbm.at[pl.ds(ids_ref[0, 0, 0, r], 1), :],
             rows_vmem.at[pl.ds(r, 1), :],
-            sems.at[r],
+            sem,
         )
 
-    def start(r, _):
+    # Launch all R row copies on one semaphore, then drain: R equal-sized
+    # DMAs in flight per grid step.
+    for r in range(R):
         row_dma(r).start()
-        return 0
-
-    def wait(r, _):
+    for r in range(R):
         row_dma(r).wait()
-        return 0
 
-    # Launch all R row copies, then drain: R DMAs in flight per grid step.
-    jax.lax.fori_loop(0, R, start, 0)
-    jax.lax.fori_loop(0, R, wait, 0)
-
-    diff = rows_vmem[...] - q_ref[0][None, :]
-    out_ref[0, :] = jnp.sum(diff * diff, axis=1)
+    diff = rows_vmem[...] - q_ref[0]
+    out_ref[0, 0, 0, :] = jnp.sum(diff * diff, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def gather_l2_tiled_pallas(base: jax.Array, ids: jax.Array, queries: jax.Array,
                            block_rows: int = 8,
                            interpret: bool = False) -> jax.Array:
-    B, M = ids.shape
+    B, K = ids.shape
     n, d = base.shape
-    if M % block_rows:
-        raise ValueError(f"M={M} must be a multiple of block_rows={block_rows}"
+    if K % block_rows:
+        raise ValueError(f"K={K} must be a multiple of block_rows={block_rows}"
                          " (wrapper pads)")
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, M // block_rows),
+    T = K // block_rows
+    # Every block's last two dims equal its array's (the TPU tiling rule):
+    # ids and the output get a unit axis before their R-wide row, the query
+    # one before its d-wide row.  ids are blocked per grid step into SMEM
+    # rather than scalar-prefetched whole: ids[B, K] at B=4096 outgrows the
+    # 1 MiB SMEM.
+    out = pl.pallas_call(
+        functools.partial(_gather_l2_tiled_kernel, block_rows=block_rows),
+        grid=(B, T),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),           # base stays in HBM
-            pl.BlockSpec((1, d), lambda b, t, ids: (b, 0)),
+            pl.BlockSpec((1, 1, 1, block_rows), lambda b, t: (b, t, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),              # base stays in HBM
+            pl.BlockSpec((1, 1, d), lambda b, t: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_rows), lambda b, t, ids: (b, t)),
+        out_specs=pl.BlockSpec((1, 1, 1, block_rows),
+                               lambda b, t: (b, t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, T, 1, block_rows), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((block_rows, d), jnp.float32),
-            pltpu.SemaphoreType.DMA((block_rows,)),
+            pltpu.SemaphoreType.DMA(()),
         ],
-    )
-    kernel = functools.partial(_gather_l2_tiled_kernel, block_rows=block_rows)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, M), jnp.float32),
         interpret=interpret,
-    )(ids.astype(jnp.int32), base.astype(jnp.float32),
-      queries.astype(jnp.float32))
+    )(ids.astype(jnp.int32).reshape(B, T, 1, block_rows),
+      base.astype(jnp.float32), queries.astype(jnp.float32)[:, None, :])
+    return out.reshape(B, K)

@@ -1,0 +1,28 @@
+"""Where JAX keeps its persistent compilation cache.
+
+Entry points (``chip_smoke.py``, ``repro.launch.serve``, ``benchmarks/run.py``)
+call :func:`use_compile_cache` once at start-up; importing ``repro`` never
+does.  A cache hits only at the path that wrote it, so the path is fixed:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and no other
+  directory is set in code;
+* otherwise ``.jax_cache/`` at the root of the checkout (git-ignored).
+"""
+
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on the persistent compilation cache; returns its directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    return str(CHECKOUT_CACHE_DIR)

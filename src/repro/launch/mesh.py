@@ -20,15 +20,11 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax ≥ 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
+from jax.sharding import AxisType
 
-    def _axis_kw(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # jax 0.4.x: Auto is the only (implicit) behavior
 
-    def _axis_kw(n_axes: int) -> dict:
-        return {}
+def _axis_kw(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

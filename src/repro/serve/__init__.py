@@ -7,5 +7,6 @@ from .resilience import (  # noqa: F401
     ResilientAnnServer,
     Response,
     ShardedResilientAnnServer,
+    TierCompileError,
     validate_query,
 )

@@ -133,14 +133,27 @@ class AnnServer:
         the resilience layer steers (ladder params, breaker tier) and the
         fault harness wraps; ``engine`` stays a parameter so breaker tiers
         remain addressable (the sharded subclass adds its own tiers)."""
+        fn, args, kw = self._program(queries, params, engine, backend)
+        return fn(*args, **kw)
+
+    def _program(self, queries, params=None, engine=None, backend=None):
+        """The jitted program one batch runs, with its arguments."""
         params = params if params is not None else self.params
         engine = engine if engine is not None else self.engine
         backend = backend if backend is not None else self.backend
         if engine != "beam":
             raise ValueError(f"unknown engine: {engine!r}")
-        if self.quantized:
-            return probing_search(self.index, queries, params, backend=backend)
-        return search(self.index, queries, params, backend=backend)
+        fn = probing_search if self.quantized else search
+        return fn, (self.index, queries, params), {"backend": backend}
+
+    def compile(self, queries, params: Optional[SearchParams] = None,
+                engine: Optional[str] = None, backend: Optional[str] = None):
+        """Lower and compile the batch program for ``queries``' shape without
+        running it; returns the ``jax.stages.Compiled`` (its ``as_text()``
+        shows which kernels the device runs).  The compiled program is
+        cached, so the next batch of that shape runs it without compiling."""
+        fn, args, kw = self._program(queries, params, engine, backend)
+        return fn.lower(*args, **kw).compile()
 
     # -- observability seams -------------------------------------------------
     def _obs_batch(self, n_live: int, res, exec_s: float) -> None:

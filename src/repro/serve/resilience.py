@@ -24,6 +24,10 @@ Containment layers, outermost first:
 5. **Circuit breaker** — repeated faults open the tier and fall back down
    the chain ``(beam, pallas) → (beam, jnp) → (beam, jnp, W=1)``; after a
    cooldown the tier is probed again (half-open) and closes on success.
+   Only runtime faults count: a tier whose program fails to lower or
+   compile raises ``TierCompileError`` out of ``drain()`` — a kernel the
+   device refuses is a program error, never quietly served by the next
+   tier.
    The chain bottoms out at ``(beam, jnp, W=1)`` — greedy best-first on
    the same lock-step engine, the minimal configuration that still
    carries the ``1/(δ·α)`` guarantee.  Exhausting every tier raises
@@ -197,6 +201,13 @@ class CircuitBreaker:
             t.open_until = self.clock() + self.cooldown_s
 
 
+def _tier_params(params: SearchParams, tier: _Tier) -> SearchParams:
+    """``params`` with the tier's pinned beam width, if it pins one."""
+    if tier.beam_width is None:
+        return params
+    return dataclasses.replace(params, beam_width=tier.beam_width)
+
+
 def default_tiers(engine: str, backend: str) -> list[tuple]:
     """Primary tier as configured, then the portable jnp backend, then
     ``(beam, jnp, W=1)`` — greedy best-first on the production engine, the
@@ -278,6 +289,13 @@ class SearchFailure(RuntimeError):
     """Raised internally when a batch exhausts every tier and retry."""
 
 
+class TierCompileError(RuntimeError):
+    """A breaker tier's program does not lower or compile for a batch shape.
+
+    That is a program error, not a transient fault: ``drain()`` raises it
+    instead of serving the batch from the next tier as ``ok``."""
+
+
 class ResilientAnnServer(AnnServer):
     """``AnnServer`` wrapped in the containment layers (module docstring).
 
@@ -305,6 +323,7 @@ class ResilientAnnServer(AnnServer):
         self._last_result = None            # full SearchResult of last batch
         self._last_coverage: float = 1.0
         self._last_max_missed: int = 0
+        self._compiled: set = set()         # (tier, params, shape) compiled
 
     # -- request path -------------------------------------------------------
     def submit(self, query, arrival_t: Optional[float] = None,
@@ -361,11 +380,49 @@ class ResilientAnnServer(AnnServer):
                 delta_bound=self.ladder.delta_bound(self.rung))
             self.metrics.gauge("serve_rung").set(self.rung)
 
+    def warm(self) -> int:
+        """Compile the primary tier's program at every ladder rung for every
+        bucket, before serving.  A deep backlog steps the ladder down a rung
+        per batch, and each rung is its own program: warmed, a step runs a
+        program already compiled instead of stalling on a compile inside
+        the overload that caused it.  Fallback tiers still compile on first
+        use (a fault brings them in, not load).  Returns the number of
+        programs compiled."""
+        tier = self.breaker.tiers[0]
+        n = 0
+        for b in self.buckets:
+            qs = np.zeros((b, self.index.dim), np.float32)
+            for r in range(len(self.ladder)):
+                n += self._compile_tier(
+                    qs, _tier_params(self.ladder.params(r), tier), tier)
+        return n
+
     # -- failure containment around the hot path -----------------------------
+    def _compile_tier(self, qs: np.ndarray, params: SearchParams,
+                      tier: _Tier) -> bool:
+        """Compile ``tier``'s program for this batch shape once, outside the
+        containment: a tier that cannot lower or compile raises
+        ``TierCompileError`` rather than opening the breaker.  Returns
+        whether it compiled (False: already compiled)."""
+        key = (tier.name, params, qs.shape)
+        if key in self._compiled:
+            return False
+        try:
+            self.compile(jnp.asarray(qs), params=params, engine=tier.engine,
+                         backend=tier.backend)
+        except Exception as e:
+            raise TierCompileError(
+                f"tier {tier.name} does not compile for a batch of shape "
+                f"{qs.shape}: {type(e).__name__}: {e}") from e
+        self._compiled.add(key)
+        return True
+
     def _search_contained(self, qs: np.ndarray, params: SearchParams):
         """One batch through retry + breaker.  Returns (result, tier_name)
         with host-materialized arrays (deferred device errors surface here,
-        inside the containment), or raises ``SearchFailure``."""
+        inside the containment), or raises ``SearchFailure``.  Each tier's
+        program is compiled before its first run; a compile failure raises
+        ``TierCompileError`` (see ``_compile_tier``)."""
         cfg = self.config
         last_err: Optional[BaseException] = None
         # Budget enough attempts to walk the whole fallback chain even when
@@ -387,9 +444,9 @@ class ResilientAnnServer(AnnServer):
                                        reason="tier_open"
                                        if i > self._last_tier else "recovery")
             self._last_tier = i
+            tier_params = _tier_params(params, tier)
+            self._compile_tier(qs, tier_params, tier)
             try:
-                tier_params = params if tier.beam_width is None else \
-                    dataclasses.replace(params, beam_width=tier.beam_width)
                 res = self._search(jnp.asarray(qs), params=tier_params,
                                    engine=tier.engine, backend=tier.backend)
                 out = (np.asarray(res.ids), np.asarray(res.dists),
@@ -569,13 +626,15 @@ class ShardedResilientAnnServer(ResilientAnnServer):
         other = "ring" if merge == "all_gather" else "all_gather"
         if len(shard_axes) == 1 and other not in merges:
             merges.append(other)
-        self._ft = {
-            m: FaultTolerantShardedSearch(
+        self._ft = {}
+        for m in merges:
+            self._ft[m] = FaultTolerantShardedSearch(
                 sidx, mesh, shard_axes=shard_axes, query_axis=query_axis,
                 merge=m, quantized=quantized, n_replicas=n_replicas,
                 registry=self.registry)
-            for m in merges
-        }
+            # the first searcher places the index on the mesh; the others
+            # and the server share that one placed copy
+            sidx = self.index = self._ft[m].sidx
         self.breaker = CircuitBreaker(
             [("sharded", m) for m in merges],
             threshold=config.breaker_threshold,
@@ -622,6 +681,15 @@ class ShardedResilientAnnServer(ResilientAnnServer):
         return self.registry.coverage()
 
     # -- search seam ---------------------------------------------------------
+    def compile(self, queries, params: Optional[SearchParams] = None,
+                engine: Optional[str] = None, backend: Optional[str] = None):
+        if engine is not None and engine != "sharded":
+            return super().compile(queries, params=params, engine=engine,
+                                   backend=backend)
+        merge = backend if backend in self._ft else next(iter(self._ft))
+        params = params if params is not None else self.params
+        return self._ft[merge].lower(queries, params).compile()
+
     def _search(self, queries, params: Optional[SearchParams] = None,
                 engine: Optional[str] = None,
                 backend: Optional[str] = None):
@@ -656,9 +724,11 @@ class ShardedResilientAnnServer(ResilientAnnServer):
             self.registry.publish(self.metrics)
         self._last_coverage = r.coverage
         self._last_max_missed = r.max_missed
+        # host placeholders for the per-query counters the merged result
+        # does not carry (device zeros would compile on the serving path)
         B = r.ids.shape[0]
-        zeros = jnp.zeros((B,), jnp.int32)
+        zeros = np.zeros((B,), np.int32)
         return SearchResult(ids=r.ids, dists=r.dists, n_dist_comps=zeros,
                             n_approx_comps=zeros, n_hops=zeros,
-                            final_l=zeros, saturated=jnp.zeros((B,), bool),
+                            final_l=zeros, saturated=np.zeros((B,), bool),
                             n_encounters=zeros)

@@ -25,22 +25,43 @@ from typing import Optional
 
 import numpy as np
 
+# Most float64 values any temporary of ``exact_knn`` holds (128 MiB).
+BLOCK_ELEMS = 1 << 24
+
 
 def exact_knn(corpus: np.ndarray, queries: np.ndarray, k: int
               ) -> tuple[np.ndarray, np.ndarray]:
     """Brute-force exact k-NN: (dists f64[B, k], ids int64[B, k]).
 
-    Euclidean distances, ascending per row; ties broken by lower id
-    (``np.argsort`` kind="stable" over the full row).  float64 throughout
-    so the oracle is strictly more precise than the f32 engines it judges.
+    Euclidean distances, ascending per row; ties broken by lower id (the
+    order of a stable sort over the full row).  float64 throughout so the
+    oracle is strictly more precise than the f32 engines it judges.
+
+    Blocked over queries and corpus rows so that no temporary holds more
+    than ``BLOCK_ELEMS`` float64 values: the result is the same at any
+    block size, and a corpus of millions of rows fits in host memory.
     """
     corpus = np.asarray(corpus, np.float64)
     queries = np.asarray(queries, np.float64)
-    if k < 1 or k > corpus.shape[0]:
-        raise ValueError(f"k={k} out of range for corpus of {corpus.shape[0]}")
-    d2 = np.sum((queries[:, None, :] - corpus[None, :, :]) ** 2, axis=-1)
-    ids = np.argsort(d2, axis=1, kind="stable")[:, :k]
-    dists = np.sqrt(np.take_along_axis(d2, ids, axis=1))
+    n, d = corpus.shape
+    if k < 1 or k > n:
+        raise ValueError(f"k={k} out of range for corpus of {n}")
+    bq = max(1, min(queries.shape[0], BLOCK_ELEMS // n))
+    bc = max(1, BLOCK_ELEMS // (bq * d))
+    ids = np.empty((queries.shape[0], k), np.int64)
+    dists = np.empty((queries.shape[0], k), np.float64)
+    for s in range(0, queries.shape[0], bq):
+        q = queries[s : s + bq]
+        d2 = np.empty((q.shape[0], n), np.float64)
+        for c in range(0, n, bc):
+            d2[:, c : c + bc] = np.sum(
+                (q[:, None, :] - corpus[None, c : c + bc, :]) ** 2, axis=-1)
+        kth = np.partition(d2, k - 1, axis=1)[:, k - 1]
+        for j in range(q.shape[0]):
+            cand = np.flatnonzero(d2[j] <= kth[j])          # ascending ids
+            top = cand[np.argsort(d2[j, cand], kind="stable")[:k]]
+            ids[s + j] = top
+            dists[s + j] = np.sqrt(d2[j, top])
     return dists, ids
 
 

@@ -7,20 +7,16 @@ import os
 import numpy as np
 import pytest
 
-try:
-    # CI hypothesis profile: derandomized (fixed seed) with bounded examples
-    # so property tests are deterministic and time-boxed; select another
-    # profile via HYPOTHESIS_PROFILE.  Absent hypothesis, property tests
-    # skip via tests/hypothesis_compat.py and no profile is needed.
-    from hypothesis import HealthCheck, settings as _hyp_settings
+from hypothesis import HealthCheck, settings as _hyp_settings
 
-    _hyp_settings.register_profile(
-        "ci", max_examples=25, deadline=None, derandomize=True,
-        suppress_health_check=[HealthCheck.too_slow])
-    _hyp_settings.register_profile("dev", max_examples=50, deadline=None)
-    _hyp_settings.load_profile(os.environ.get("HYPOTHESIS_PROFILE", "ci"))
-except ModuleNotFoundError:
-    pass
+# CI hypothesis profile: derandomized (fixed seed) with bounded examples so
+# property tests are deterministic and time-boxed; select another profile
+# via HYPOTHESIS_PROFILE.
+_hyp_settings.register_profile(
+    "ci", max_examples=25, deadline=None, derandomize=True,
+    suppress_health_check=[HealthCheck.too_slow])
+_hyp_settings.register_profile("dev", max_examples=50, deadline=None)
+_hyp_settings.load_profile(os.environ.get("HYPOTHESIS_PROFILE", "ci"))
 
 
 def gmm(n, d, k_clusters, seed, scale=0.35):

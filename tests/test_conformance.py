@@ -28,10 +28,11 @@ Layers:
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import SearchParams, ags_search, build_exact, probing_search, search
 from repro.core.emqg import from_graph
+from repro.testing import oracle as oracle_mod
 from repro.testing.oracle import check_delta_bound, exact_knn, recall_at_k
 
 from conftest import gmm
@@ -246,6 +247,26 @@ def test_oracle_permutation_equivariant(conformance_seed):
     d1, i1 = exact_knn(base[perm], queries, 4)
     np.testing.assert_allclose(d0, d1, rtol=1e-12, atol=1e-12)
     np.testing.assert_array_equal(perm[i1], i0)
+
+
+@pytest.mark.parametrize("block_elems", [1, 200, 1 << 24])
+def test_oracle_blocked_equals_dense(conformance_seed, block_elems,
+                                     monkeypatch):
+    """Blocking over queries and corpus rows changes nothing: same float64
+    distances, same ids, ties (planted duplicate rows) to the lower id."""
+    base = gmm(120, 8, 4, seed=conformance_seed + 7).astype(np.float64)
+    base[50] = base[3]
+    base[90] = base[3]
+    queries = np.concatenate([gmm(5, 8, 4, seed=conformance_seed + 8),
+                              base[3:4]])
+    d2 = np.sum((queries[:, None, :] - base[None, :, :]) ** 2, axis=-1)
+    dense_i = np.argsort(d2, axis=1, kind="stable")[:, :6]
+    dense_d = np.sqrt(np.take_along_axis(d2, dense_i, axis=1))
+    monkeypatch.setattr(oracle_mod, "BLOCK_ELEMS", block_elems)
+    d, i = exact_knn(base, queries, 6)
+    np.testing.assert_array_equal(i, dense_i)
+    np.testing.assert_array_equal(d, dense_d)
+    assert list(i[-1, :3]) == [3, 50, 90]
 
 
 def test_oracle_detects_violation():

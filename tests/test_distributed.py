@@ -172,8 +172,6 @@ cell = build_cell(arch, arch.shapes["serve_p99"], mesh)
 compiled = cell.lower().compile()
 mem = compiled.memory_analysis()
 cost = compiled.cost_analysis()
-if isinstance(cost, list):  # jax 0.4.x returns [dict], newer jax a dict
-    cost = cost[0]
 coll = parse_collectives(compiled.as_text())
 assert cost.get("flops", 0) > 0
 print("OK", int(mem.temp_size_in_bytes), coll["total_operand_bytes"])

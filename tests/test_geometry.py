@@ -2,12 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-pytest.importorskip(
-    "hypothesis",
-    reason="optional test dependency; every test here is a property test")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.core import geometry
 

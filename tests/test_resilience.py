@@ -21,6 +21,7 @@ from repro.serve import (
     DegradationLadder,
     ResilienceConfig,
     ResilientAnnServer,
+    TierCompileError,
     validate_query,
 )
 from repro.serve.resilience import default_tiers
@@ -163,6 +164,29 @@ def test_overload_engages_ladder_with_finite_bounds(tiny):
     for r in degraded[:5]:
         assert r.ids.shape == (PARAMS.k,)
         assert (np.diff(r.dists) >= -1e-5).all()
+
+
+def test_warm_compiles_every_rung_before_an_overload(tiny):
+    """``warm()`` compiles the primary tier at every rung for every bucket,
+    so a burst that walks the ladder down compiles nothing while serving."""
+    import jax.monitoring
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **kw: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    srv = ResilientAnnServer(
+        tiny["graph"], PARAMS,
+        config=fast_cfg(degrade_depth=8, recover_depth=2, n_rungs=3),
+        max_batch=8, buckets=(4, 8))
+    assert srv.warm() == 2 * 3                  # buckets × rungs
+    assert srv.warm() == 0                      # already compiled
+    n0 = len(compiles)
+    srv.submit_many(tiny["queries"][:36])       # last batch fills bucket 4
+    rs = srv.drain()
+    assert all(r.ok for r in rs)
+    assert {r.rung for r in rs} == {1, 2}
+    assert len(compiles) == n0, "serving compiled after warm()"
 
 
 def test_ladder_recovers_when_queue_drains(tiny):
@@ -315,6 +339,34 @@ def test_every_tier_dead_yields_failed_responses_not_a_crash(tiny):
     assert srv.stats.n_failed == 8
     assert inj.tier_log[-1] == ("beam", "jnp", 1)
     assert {t[0] for t in inj.tier_log} == {"beam"}
+
+
+@pytest.mark.faults
+def test_tier_that_fails_to_lower_raises_not_falls_back(tiny, monkeypatch):
+    """A kernel the device refuses is a program error: the batch must not
+    be served from ``(beam, jnp)`` as ``ok``.  The kernel is replaced by one
+    that fails while the program is traced and lowered, as Mosaic does for
+    a block layout the chip cannot tile."""
+    from repro.kernels.l2dist import ops as l2ops
+
+    def refused(*_a, **_kw):
+        raise NotImplementedError("Unsupported block layout (injected)")
+
+    monkeypatch.setattr(l2ops, "gather_l2_tiled", refused)
+    params = dataclasses.replace(PARAMS, l_max=48)     # a fresh trace
+    srv = ResilientAnnServer(tiny["graph"], params, config=fast_cfg(),
+                             max_batch=8, buckets=(8,),
+                             backend="kernel_tiled")
+    srv.submit_many(tiny["queries"][:8])
+    with pytest.raises(TierCompileError, match="beam/kernel_tiled"):
+        srv.drain()
+    assert srv.stats.n_requests == 0 and srv.stats.n_fallback == 0
+    # a runtime fault on the same server still walks the breaker
+    monkeypatch.undo()
+    with inject_search_faults(srv, FaultPlan(fail_first=1)) as inj:
+        srv.submit_many(tiny["queries"][:8])
+        rs = srv.drain()
+    assert inj.n_failed == 1 and all(r.ok for r in rs)
 
 
 def test_circuit_breaker_half_open_recovery():

@@ -1,0 +1,122 @@
+"""Ahead-of-time compiles of the serve path's kernels for a described TPU v5e.
+
+Nothing runs: the TPU compiler installed with JAX compiles for a chip that
+is described, not attached, and refuses what the chip would refuse (block
+layouts off the (8, 128) tiling rule, SMEM or VMEM overflow, casts Mosaic
+does not lower).  Every case asserts that the Pallas kernel survived into
+the compiled program (``tpu_custom_call``) rather than a jnp fallback.
+
+Shapes are the paper's SIFT1M deployment (``configs/sift1m.py``): n=1M,
+d=128, M=64, k=10, l_max=512, online batch 256 and bulk batch 4096.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import SearchParams, probing_search
+from repro.core.types import EMQGIndex, GraphIndex, RaBitQCodes
+from repro.kernels.bitdot import ops as bitops
+from repro.kernels.l2dist import ops as l2ops
+
+N, D, M, K = 1_000_000, 128, 64, 10
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("B", [256, 4096])
+@pytest.mark.parametrize("W", [1, 4])
+def test_gather_l2_tiled_compiles(one_chip, B, W):
+    compiled = l2ops.gather_l2_tiled.lower(
+        _sds((N, D), jnp.float32, one_chip),
+        _sds((B, W * M), jnp.int32, one_chip),
+        _sds((B, D), jnp.float32, one_chip),
+        interpret=False).compile()
+    _assert_kernel(compiled)
+
+
+def test_bitdot_compiles(one_chip):
+    m, words = 4096, D // 32
+    compiled = bitops.bitdot.lower(
+        _sds((m, words), jnp.uint32, one_chip),
+        _sds((D,), jnp.float32, one_chip),
+        interpret=False).compile()
+    _assert_kernel(compiled)
+
+
+def test_fused_estimate_compiles(one_chip):
+    m, words = 4096, D // 32
+    compiled = bitops.fused_estimate.lower(
+        _sds((m, words), jnp.uint32, one_chip),
+        _sds((m,), jnp.float32, one_chip),
+        _sds((m,), jnp.float32, one_chip),
+        _sds((D,), jnp.float32, one_chip),
+        _sds((), jnp.float32, one_chip),
+        dim=D, interpret=False).compile()
+    _assert_kernel(compiled)
+
+
+def test_probing_search_program_compiles(one_chip, monkeypatch):
+    """The whole served program: Algorithm 5 at B=256 with the exact tier on
+    ``gather_l2_tiled``.  This process's backend is the CPU, where the
+    kernel wrapper picks interpret mode; steer it to the compiled kernel."""
+    monkeypatch.setattr(l2ops, "_on_cpu", lambda: False)
+    B = 256
+    graph = GraphIndex(vectors=_sds((N, D), jnp.float32, one_chip),
+                       neighbors=_sds((N, M), jnp.int32, one_chip),
+                       medoid=_sds((), jnp.int32, one_chip),
+                       kind="delta_emqg", delta=0.2)
+    codes = RaBitQCodes(codes=_sds((N, D // 32), jnp.uint32, one_chip),
+                        norms=_sds((N,), jnp.float32, one_chip),
+                        ip_xo=_sds((N,), jnp.float32, one_chip),
+                        rotation=_sds((D, D), jnp.float32, one_chip),
+                        center=_sds((D,), jnp.float32, one_chip), dim=D)
+    params = SearchParams(k=K, l0=K, l_max=512, alpha=1.2, adaptive=True,
+                          max_hops=4096)
+    compiled = probing_search.lower(
+        EMQGIndex(graph=graph, codes=codes),
+        _sds((B, D), jnp.float32, one_chip), params,
+        backend="kernel_tiled").compile()
+    _assert_kernel(compiled)
