@@ -21,7 +21,6 @@ SECTIONS = {
     "exp9": ("ablation", "Exp-9 ablation (Fig. 10)"),
     "retrieval": ("retrieval", "δ-EMQG behind recsys retrieval_cand"),
     "kernels": ("kernels_bench", "Pallas kernel microbench"),
-    "roofline": ("roofline", "§Roofline table from the dry-run"),
 }
 
 

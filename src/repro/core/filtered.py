@@ -17,6 +17,7 @@ is a property of the graph, not of the result filter.
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Optional
 
@@ -58,9 +59,4 @@ def filtered_search(graph: GraphIndex, queries, filter_mask, k: int,
     res, cand_ids, cand_dists = search(graph, jnp.asarray(queries), p,
                                        with_candidates=True)
     ids, dists = _filter_topk(cand_ids, cand_dists, mask, k)
-    return SearchResult(ids=ids, dists=dists,
-                        n_dist_comps=res.n_dist_comps,
-                        n_approx_comps=res.n_approx_comps,
-                        n_hops=res.n_hops, final_l=res.final_l,
-                        saturated=res.saturated,
-                        n_encounters=res.n_encounters)
+    return dataclasses.replace(res, ids=ids, dists=dists)

@@ -71,6 +71,7 @@ class _BeamPState(NamedTuple):
     n_approx: jax.Array    # int32[B]
     n_enc: jax.Array       # int32[B]  candidate encounters (pre-dedup)
     n_hops: jax.Array      # int32[B]
+    n_iters: jax.Array     # int32[B]  loop iterations the row was active in
     done: jax.Array        # bool[B]
     saturated: jax.Array   # bool[B]
 
@@ -107,6 +108,7 @@ def _beam_probing_batch(
         n_approx=jnp.zeros((B,), jnp.int32),
         n_enc=jnp.ones((B,), jnp.int32),
         n_hops=jnp.zeros((B,), jnp.int32),
+        n_iters=jnp.zeros((B,), jnp.int32),
         done=jnp.zeros((B,), jnp.bool_),
         saturated=jnp.zeros((B,), jnp.bool_),
     )
@@ -114,85 +116,104 @@ def _beam_probing_batch(
     def active_mask(s: _BeamPState):
         return (~s.done) & (s.n_hops < p.max_hops)
 
+    # The phases run under the exact engine's ``hop.*`` named scopes
+    # (``search._beam_search_batch``), with the RaBitQ estimates under
+    # ``hop.estimate``; a scope only tags the ops' metadata.
     def cond(s: _BeamPState):
-        return jnp.any(active_mask(s))
+        with jax.named_scope("hop.transition"):
+            return jnp.any(active_mask(s))
 
     def body(s: _BeamPState) -> _BeamPState:
-        active = active_mask(s)
-        win_e = (pos < s.l[:, None]) & (s.ce_ids >= 0) & (~s.ce_vis)
-        win_e &= active[:, None]
-        win_a = (pos < s.l[:, None]) & (s.ca_ids >= 0) & (~s.ca_prb)
-        win_a &= active[:, None]
-        has_u = jnp.any(win_e, axis=1)
-        has_w = jnp.any(win_a, axis=1)
-        d2_u = jnp.min(jnp.where(win_e, s.ce_d2, jnp.inf), axis=1)
-        d2_w = jnp.min(jnp.where(win_a, s.ca_d2, jnp.inf), axis=1)
+        with jax.named_scope("hop.select"):
+            active = active_mask(s)
+            win_e = (pos < s.l[:, None]) & (s.ce_ids >= 0) & (~s.ce_vis)
+            win_e &= active[:, None]
+            win_a = (pos < s.l[:, None]) & (s.ca_ids >= 0) & (~s.ca_prb)
+            win_a &= active[:, None]
+            has_u = jnp.any(win_e, axis=1)
+            has_w = jnp.any(win_a, axis=1)
+            d2_u = jnp.min(jnp.where(win_e, s.ce_d2, jnp.inf), axis=1)
+            d2_w = jnp.min(jnp.where(win_a, s.ca_d2, jnp.inf), axis=1)
 
-        # NeedProbing (lines 22-28): probe when the exact frontier stopped
-        # improving and the approx tier has something closer.
-        need_probe = jnp.where(
-            ~has_u,
-            has_w,
-            (d2_u > s.d2_last) & has_w & (d2_w < d2_u),
-        )
-        probing = active & need_probe
-        expanding = active & ~need_probe & has_u
-        conv = active & ~has_u & ~has_w
+            # NeedProbing (lines 22-28): probe when the exact frontier
+            # stopped improving and the approx tier has something closer.
+            need_probe = jnp.where(
+                ~has_u,
+                has_w,
+                (d2_u > s.d2_last) & has_w & (d2_w < d2_u),
+            )
+            probing = active & need_probe
+            expanding = active & ~need_probe & has_u
+            conv = active & ~has_u & ~has_w
 
-        # -- probe branch: exact distances for W best unprobed approx --------
-        sel_w, selv_w = select_top_w(s.ca_d2, win_a, W)
-        selv_w &= probing[:, None]
-        prb_sel = jnp.take_along_axis(s.ca_prb, sel_w, axis=1) | selv_w
-        ca_prb = s.ca_prb.at[rows, sel_w].set(prb_sel)
-        w_ids = jnp.where(
-            selv_w, jnp.take_along_axis(s.ca_ids, sel_w, axis=1), INVALID_ID)
-        d2_probe = batch_exact(queries, w_ids)                 # [B, W] fused
-        n_dist = s.n_dist + jnp.sum(w_ids >= 0, axis=1).astype(jnp.int32)
+            # -- probe branch: the W best unprobed approx candidates ---------
+            sel_w, selv_w = select_top_w(s.ca_d2, win_a, W)
+            selv_w &= probing[:, None]
+            prb_sel = jnp.take_along_axis(s.ca_prb, sel_w, axis=1) | selv_w
+            ca_prb = s.ca_prb.at[rows, sel_w].set(prb_sel)
+            w_ids = jnp.where(
+                selv_w, jnp.take_along_axis(s.ca_ids, sel_w, axis=1),
+                INVALID_ID)
 
-        # -- expand branch: approx distances for W·M neighbor ids ------------
-        sel_u, selv_u = select_top_w(s.ce_d2, win_e, W)
-        selv_u &= expanding[:, None]
-        vis_sel = jnp.take_along_axis(s.ce_vis, sel_u, axis=1) | selv_u
-        ce_vis = s.ce_vis.at[rows, sel_u].set(vis_sel)
-        u_ids = jnp.where(
-            selv_u, jnp.take_along_axis(s.ce_ids, sel_u, axis=1), INVALID_ID)
-        d2_u_sel = jnp.where(
-            selv_u, jnp.take_along_axis(s.ce_d2, sel_u, axis=1), -jnp.inf)
-        # "last expanded" = the worst of this hop's frontier (W=1: exactly u).
-        d2_last = jnp.where(expanding, jnp.max(d2_u_sel, axis=1), s.d2_last)
+            # -- expand branch: the W best unexpanded exact candidates -------
+            sel_u, selv_u = select_top_w(s.ce_d2, win_e, W)
+            selv_u &= expanding[:, None]
+            vis_sel = jnp.take_along_axis(s.ce_vis, sel_u, axis=1) | selv_u
+            ce_vis = s.ce_vis.at[rows, sel_u].set(vis_sel)
+            u_ids = jnp.where(
+                selv_u, jnp.take_along_axis(s.ce_ids, sel_u, axis=1),
+                INVALID_ID)
+            d2_u_sel = jnp.where(
+                selv_u, jnp.take_along_axis(s.ce_d2, sel_u, axis=1), -jnp.inf)
+            # "last expanded" = the worst of this hop's frontier (W=1: u).
+            d2_last = jnp.where(expanding, jnp.max(d2_u_sel, axis=1),
+                                s.d2_last)
+            n_hops = s.n_hops + jnp.sum(selv_w, axis=1).astype(jnp.int32) \
+                + jnp.sum(selv_u, axis=1).astype(jnp.int32)
 
-        nbrs = jnp.take(neighbors, jnp.maximum(u_ids, 0), axis=0)
-        nbrs = jnp.where(selv_u[:, :, None], nbrs, INVALID_ID).reshape(B, W * M)
-        fresh = (nbrs >= 0) & ~bitset_test(s.seen, nbrs)
-        new_ids = unique_per_row(nbrs, fresh)
-        seen = bitset_set(s.seen, new_ids)
-        d2a = batch_approx(new_ids)                            # [B, W·M]
-        n_approx = s.n_approx + jnp.sum(new_ids >= 0, axis=1).astype(jnp.int32)
-        # encounters: valid neighbor ids pre-dedup, plus probed candidates
-        n_enc = s.n_enc + jnp.sum(nbrs >= 0, axis=1).astype(jnp.int32) \
-            + jnp.sum(w_ids >= 0, axis=1).astype(jnp.int32)
+        with jax.named_scope("hop.distance"):                # probe: exact
+            d2_probe = batch_exact(queries, w_ids)             # [B, W] fused
+            n_dist = s.n_dist + jnp.sum(w_ids >= 0, axis=1).astype(jnp.int32)
 
-        n_hops = s.n_hops + jnp.sum(selv_w, axis=1).astype(jnp.int32) \
-            + jnp.sum(selv_u, axis=1).astype(jnp.int32)
+        with jax.named_scope("hop.expand"):
+            nbrs = jnp.take(neighbors, jnp.maximum(u_ids, 0), axis=0)
+            nbrs = jnp.where(selv_u[:, :, None], nbrs,
+                             INVALID_ID).reshape(B, W * M)
+            # encounters: valid neighbor ids pre-dedup, plus probed ones
+            n_enc = s.n_enc + jnp.sum(nbrs >= 0, axis=1).astype(jnp.int32) \
+                + jnp.sum(w_ids >= 0, axis=1).astype(jnp.int32)
+
+        with jax.named_scope("hop.visited"):
+            fresh = (nbrs >= 0) & ~bitset_test(s.seen, nbrs)
+            new_ids = unique_per_row(nbrs, fresh)
+            seen = bitset_set(s.seen, new_ids)
+
+        with jax.named_scope("hop.estimate"):                # expand: approx
+            d2a = batch_approx(new_ids)                        # [B, W·M]
+            n_approx = s.n_approx \
+                + jnp.sum(new_ids >= 0, axis=1).astype(jnp.int32)
 
         # -- merges (per query only one branch contributes real entries) -----
-        ce_ids, ce_d2, ce_vis = batch_merge_topc(
-            s.ce_ids, s.ce_d2, ce_vis,
-            w_ids, d2_probe, jnp.zeros_like(w_ids, jnp.bool_), C)
-        ca_ids, ca_d2, ca_prb = batch_merge_topc(
-            s.ca_ids, s.ca_d2, ca_prb,
-            new_ids, d2a, jnp.zeros_like(fresh), C)
+        with jax.named_scope("hop.merge"):
+            ce_ids, ce_d2, ce_vis = batch_merge_topc(
+                s.ce_ids, s.ce_d2, ce_vis,
+                w_ids, d2_probe, jnp.zeros_like(w_ids, jnp.bool_), C)
+            ca_ids, ca_d2, ca_prb = batch_merge_topc(
+                s.ca_ids, s.ca_d2, ca_prb,
+                new_ids, d2a, jnp.zeros_like(fresh), C)
 
         # -- adaptive transition for exhausted queries -----------------------
-        l, done, saturated = adaptive_transition(
-            p, ce_d2, s.l, s.done, s.saturated, conv)
+        with jax.named_scope("hop.transition"):
+            l, done, saturated = adaptive_transition(
+                p, ce_d2, s.l, s.done, s.saturated, conv)
+            n_iters = s.n_iters + active.astype(jnp.int32)
 
         return _BeamPState(
             ce_ids=ce_ids, ce_d2=ce_d2, ce_vis=ce_vis,
             ca_ids=ca_ids, ca_d2=ca_d2, ca_prb=ca_prb,
             seen=seen, d2_last=d2_last, l=l, n_dist=n_dist,
-            n_approx=n_approx, n_enc=n_enc, n_hops=n_hops, done=done,
-            saturated=saturated)
+            n_approx=n_approx, n_enc=n_enc, n_hops=n_hops, n_iters=n_iters,
+            done=done, saturated=saturated)
 
     return jax.lax.while_loop(cond, body, st)
 
@@ -240,6 +261,7 @@ def probing_search(
         final_l=st.l,
         saturated=st.saturated,
         n_encounters=st.n_enc,
+        n_iters=st.n_iters,
     )
     if with_candidates:
         return res, st.ce_ids, jnp.sqrt(jnp.maximum(st.ce_d2, 0.0))
@@ -307,4 +329,5 @@ def ags_search(index: EMQGIndex, queries: jax.Array, params: SearchParams,
         final_l=st.l,
         saturated=st.saturated,
         n_encounters=st.n_enc,
+        n_iters=st.n_iters,
     )

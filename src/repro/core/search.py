@@ -158,6 +158,7 @@ class _BeamState(NamedTuple):
     n_dist: jax.Array      # int32[B]    exact distance evaluations
     n_enc: jax.Array       # int32[B]    candidate encounters (pre-dedup)
     n_hops: jax.Array      # int32[B]    expansions
+    n_iters: jax.Array     # int32[B]    loop iterations the row was active in
     done: jax.Array        # bool[B]
     saturated: jax.Array   # bool[B]     l hit l_max before the α-rule fired
 
@@ -231,7 +232,8 @@ def faithful_prune_merge(cand_ids, cand_d2, cand_vis, new_ids, d2_new,
     # pruned ∧ unexpanded → clearable; ids are unique per row (buffer entries
     # are unique and fresh ids were, by definition, not in the buffer)
     clearable = jnp.where(keep | vis_s, INVALID_ID, ids_s)
-    seen = bitset_clear(seen, clearable)
+    with jax.named_scope("hop.visited"):
+        seen = bitset_clear(seen, clearable)
     return (jnp.where(keep, ids_s, INVALID_ID)[:, :cap],
             jnp.where(keep, d2_s, jnp.inf)[:, :cap],
             (keep & vis_s)[:, :cap],
@@ -265,6 +267,7 @@ def _beam_search_batch(
         n_dist=jnp.ones((B,), jnp.int32),
         n_enc=jnp.ones((B,), jnp.int32),
         n_hops=jnp.zeros((B,), jnp.int32),
+        n_iters=jnp.zeros((B,), jnp.int32),
         done=jnp.zeros((B,), jnp.bool_),
         saturated=jnp.zeros((B,), jnp.bool_),
     )
@@ -272,58 +275,73 @@ def _beam_search_batch(
     def active_mask(s: _BeamState):
         return (~s.done) & (s.n_hops < p.max_hops)
 
+    # Each phase of an iteration runs under a ``hop.*`` named scope, which
+    # only tags the ops' metadata: a profile of the compiled loop can then
+    # charge every device op to its phase, whatever XLA names the fusion.
     def cond(s: _BeamState):
-        return jnp.any(active_mask(s))
+        with jax.named_scope("hop.transition"):
+            return jnp.any(active_mask(s))
 
     def body(s: _BeamState) -> _BeamState:
-        active = active_mask(s)
-        window = (pos < s.l[:, None]) & (s.cand_ids >= 0) & (~s.cand_vis)
-        window &= active[:, None]
-        has_frontier = jnp.any(window, axis=1)
-
         # -- frontier selection: W best unvisited in-window per query --------
-        sel, selv = select_top_w(s.cand_d2, window, W)
-        selv &= (active & has_frontier)[:, None]
-        vis_sel = jnp.take_along_axis(s.cand_vis, sel, axis=1) | selv
-        cand_vis = s.cand_vis.at[rows, sel].set(vis_sel)
-        u_ids = jnp.where(
-            selv, jnp.take_along_axis(s.cand_ids, sel, axis=1), INVALID_ID)
+        with jax.named_scope("hop.select"):
+            active = active_mask(s)
+            window = (pos < s.l[:, None]) & (s.cand_ids >= 0) & (~s.cand_vis)
+            window &= active[:, None]
+            has_frontier = jnp.any(window, axis=1)
+            sel, selv = select_top_w(s.cand_d2, window, W)
+            selv &= (active & has_frontier)[:, None]
+            vis_sel = jnp.take_along_axis(s.cand_vis, sel, axis=1) | selv
+            cand_vis = s.cand_vis.at[rows, sel].set(vis_sel)
+            u_ids = jnp.where(
+                selv, jnp.take_along_axis(s.cand_ids, sel, axis=1), INVALID_ID)
+            n_hops = s.n_hops + jnp.sum(selv, axis=1).astype(jnp.int32)
 
-        # -- neighbor gather + bitset dedup ---------------------------------
-        nbrs = jnp.take(graph.neighbors, jnp.maximum(u_ids, 0), axis=0)
-        nbrs = jnp.where(selv[:, :, None], nbrs, INVALID_ID).reshape(B, W * M)
-        # encounters: every valid neighbor id this hop produced, pre-dedup —
-        # the dedup-independent Exp-5 counter (ROADMAP: the bitset never
-        # re-evaluates pruned-then-reencountered nodes, so n_dist undercounts)
-        n_enc = s.n_enc + jnp.sum(nbrs >= 0, axis=1).astype(jnp.int32)
-        fresh = (nbrs >= 0) & ~bitset_test(s.seen, nbrs)
-        new_ids = unique_per_row(nbrs, fresh)                  # [B, W·M]
-        seen = bitset_set(s.seen, new_ids)
+        # -- neighbor gather ------------------------------------------------
+        with jax.named_scope("hop.expand"):
+            nbrs = jnp.take(graph.neighbors, jnp.maximum(u_ids, 0), axis=0)
+            nbrs = jnp.where(selv[:, :, None], nbrs,
+                             INVALID_ID).reshape(B, W * M)
+            # encounters: every valid neighbor id this hop produced,
+            # pre-dedup — the dedup-independent Exp-5 counter (the bitset
+            # never re-evaluates pruned-then-reencountered nodes, so n_dist
+            # undercounts)
+            n_enc = s.n_enc + jnp.sum(nbrs >= 0, axis=1).astype(jnp.int32)
+
+        # -- bitset dedup -----------------------------------------------------
+        with jax.named_scope("hop.visited"):
+            fresh = (nbrs >= 0) & ~bitset_test(s.seen, nbrs)
+            new_ids = unique_per_row(nbrs, fresh)              # [B, W·M]
+            seen = bitset_set(s.seen, new_ids)
 
         # -- the hot path: one fused gather+L2 over the whole batch ----------
-        d2_new = batch_dist(queries, new_ids)
-        n_evals = jnp.sum(new_ids >= 0, axis=1).astype(jnp.int32)
-        n_dist = s.n_dist + n_evals
-        n_hops = s.n_hops + jnp.sum(selv, axis=1).astype(jnp.int32)
+        with jax.named_scope("hop.distance"):
+            d2_new = batch_dist(queries, new_ids)
+            n_dist = s.n_dist + jnp.sum(new_ids >= 0, axis=1).astype(jnp.int32)
 
-        if faithful_prune:
-            cand_ids, cand_d2, cand_vis, seen = faithful_prune_merge(
-                s.cand_ids, s.cand_d2, cand_vis, new_ids, d2_new,
-                seen, s.l, C)
-        else:
-            cand_ids, cand_d2, cand_vis = batch_merge_topc(
-                s.cand_ids, s.cand_d2, cand_vis,
-                new_ids, d2_new, jnp.zeros_like(fresh), C)
+        with jax.named_scope("hop.merge"):
+            if faithful_prune:
+                cand_ids, cand_d2, cand_vis, seen = faithful_prune_merge(
+                    s.cand_ids, s.cand_d2, cand_vis, new_ids, d2_new,
+                    seen, s.l, C)
+            else:
+                cand_ids, cand_d2, cand_vis = batch_merge_topc(
+                    s.cand_ids, s.cand_d2, cand_vis,
+                    new_ids, d2_new, jnp.zeros_like(fresh), C)
 
         # -- adaptive transition for window-exhausted queries ----------------
-        conv = active & ~has_frontier
-        l, done, saturated = adaptive_transition(
-            p, cand_d2, s.l, s.done, s.saturated, conv)
+        with jax.named_scope("hop.transition"):
+            conv = active & ~has_frontier
+            l, done, saturated = adaptive_transition(
+                p, cand_d2, s.l, s.done, s.saturated, conv)
+            # lock-step iterations this row was active in: the batch's
+            # largest is the loop's trip count
+            n_iters = s.n_iters + active.astype(jnp.int32)
 
         return _BeamState(cand_ids=cand_ids, cand_d2=cand_d2,
                           cand_vis=cand_vis, seen=seen, l=l, n_dist=n_dist,
-                          n_enc=n_enc, n_hops=n_hops, done=done,
-                          saturated=saturated)
+                          n_enc=n_enc, n_hops=n_hops, n_iters=n_iters,
+                          done=done, saturated=saturated)
 
     return jax.lax.while_loop(cond, body, st)
 
@@ -369,6 +387,7 @@ def search(
         final_l=st.l,
         saturated=st.saturated,
         n_encounters=st.n_enc,
+        n_iters=st.n_iters,
     )
     if with_candidates:
         return res, st.cand_ids, jnp.sqrt(jnp.maximum(st.cand_d2, 0.0))

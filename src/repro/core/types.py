@@ -142,6 +142,9 @@ class SearchResult:
     dedup-independent and identical across engines at ``beam_width=1``.
     ``saturated`` flags queries whose adaptive ``l`` hit the buffer cap
     before the α-stop rule fired (bound may not hold for those).
+    ``n_iters`` counts the lock-step loop iterations each row was active in;
+    its largest value over the batch's rows is the loop's trip count.  A
+    result made without the loop leaves it ``None``.
     """
 
     ids: jax.Array
@@ -152,6 +155,7 @@ class SearchResult:
     final_l: jax.Array
     saturated: jax.Array
     n_encounters: jax.Array = None
+    n_iters: jax.Array = None
 
 
 @_register

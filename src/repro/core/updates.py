@@ -172,12 +172,8 @@ def search_live(live: LiveIndex, queries, k: int, alpha: float = 1.2,
         for j, (d, i) in enumerate(keep):
             out_ids[b, j] = i
             out_d[b, j] = d
-    return SearchResult(ids=jnp.asarray(out_ids), dists=jnp.asarray(out_d),
-                        n_dist_comps=res.n_dist_comps,
-                        n_approx_comps=res.n_approx_comps,
-                        n_hops=res.n_hops, final_l=res.final_l,
-                        saturated=res.saturated,
-                        n_encounters=res.n_encounters)
+    return dataclasses.replace(res, ids=jnp.asarray(out_ids),
+                               dists=jnp.asarray(out_d))
 
 
 def consolidate(live: LiveIndex) -> LiveIndex:

@@ -15,7 +15,7 @@ and the 2×16×16 multi-pod mesh:
     parse compiled HLO  → per-collective operand bytes for §Roofline
 
 Results are appended to a JSON file (default
-``benchmarks/results/dryrun.json``) that ``benchmarks/roofline.py`` reads.
+``benchmarks/results/dryrun.json``).
 
 Usage:
     python -m repro.launch.dryrun                       # everything

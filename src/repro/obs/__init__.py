@@ -26,6 +26,10 @@ search_approx_comps_total               counter    quantized evals (δ-EMQG)
 search_hops_total                       counter    expansions
 search_encounters_total                 counter    pre-dedup candidate encounters
 search_saturated_total                  counter    queries whose adaptive l capped
+search_row_iters_total                  counter    lock-step iterations live rows
+                                                   were active in (Σ n_iters)
+search_slot_iters_total                 counter    lock-step iterations × bucket
+                                                   rows (the loop's slots)
 search_final_l                          histogram  per-query final beam length
 shard_live{shard}                       gauge      1 = some replica live
 shard_coverage                          gauge      live logical shards / S
@@ -49,12 +53,25 @@ build_nodes_total                       counter    nodes processed by the builde
 ======================================  =========  ==============================
 
 Span taxonomy: ``serve.request`` (child ``serve.queue_wait``) per request;
-``serve.batch`` per dispatched batch with children ``serve.batch_form``,
-``serve.device_execute`` (children ``shard{shard,live}`` under sharded
-fan-out) and ``serve.merge``.
+``serve.batch`` per dispatched batch (attribute ``iters``: the batch's
+lock-step iteration count) with children ``serve.batch_form``,
+``serve.device_execute`` and ``serve.merge``.  ``serve.device_execute``
+holds ``serve.put`` (queries to the device), ``serve.launch`` (the search
+call, up to its return) and ``serve.fetch`` (the one blocking read of the
+result), and ``shard{shard,live}`` under sharded fan-out.  The tracer
+writes each of these spans, except the retroactive request spans, into a
+running ``jax.profiler`` trace too (the mirror).
 
-Everything here is stdlib-only and observation-only: enabling metrics can
-not change search results (pinned bit-identical by ``tests/test_obs.py``).
+Device phases: the beam engines' loop bodies run each phase under a
+``jax.named_scope`` — ``hop.select``, ``hop.expand``, ``hop.visited``,
+``hop.distance``, ``hop.merge``, ``hop.transition``, and in the probing
+engine ``hop.estimate`` (the RaBitQ estimates) — so the compiled ops'
+metadata names the phase each one belongs to.
+
+Everything here is stdlib-only (the mirror imports ``jax`` at the first
+span, and is off where it cannot) and observation-only: enabling metrics
+can not change search results (pinned bit-identical by
+``tests/test_obs.py``).
 """
 
 from .exporters import (  # noqa: F401
@@ -105,6 +122,10 @@ def declare_serve_metrics(registry: MetricsRegistry,
                      help="pre-dedup candidate encounters")
     registry.counter("search_saturated_total",
                      help="queries whose adaptive l hit the cap")
+    registry.counter("search_row_iters_total",
+                     help="lock-step iterations live rows were active in")
+    registry.counter("search_slot_iters_total",
+                     help="lock-step iterations times bucket rows")
     registry.histogram("search_final_l", buckets=DEFAULT_WORK_BUCKETS,
                        help="per-query final beam length")
     registry.gauge("shard_coverage",
@@ -137,10 +158,15 @@ def declare_serve_metrics(registry: MetricsRegistry,
 
 def record_search_result(registry: MetricsRegistry, res,
                          n_live: int = None) -> None:
-    """Aggregate one batch's device-side ``SearchResult`` counters into
-    host-side metrics.  ``n_live`` restricts the aggregation to the first
-    ``n_live`` rows (padded rows repeat the last real query — counting them
-    would double-bill the pad).  Read-only on ``res``.
+    """Aggregate one batch's ``SearchResult`` counters into host-side
+    metrics.  ``n_live`` restricts the aggregation to the first ``n_live``
+    rows (padded rows repeat the last real query — counting them would
+    double-bill the pad).  Read-only on ``res``; pass it fetched to the host
+    (``jax.device_get``), or each field is read from the device in turn.
+
+    ``n_iters``, where the result has it, gives the lock-step counters: the
+    live rows' iterations, and the loop's trip count (the largest over all
+    rows, pads included) times the rows the loop ran on.
     """
     import numpy as np  # deferred: keep `repro.obs` importable stdlib-only
 
@@ -157,8 +183,13 @@ def record_search_result(registry: MetricsRegistry, res,
     if getattr(res, "n_encounters", None) is not None:
         registry.counter("search_encounters_total").inc(
             float(rows(res.n_encounters).sum()))
+    if getattr(res, "n_iters", None) is not None:
+        it = np.asarray(res.n_iters)
+        registry.counter("search_row_iters_total").inc(
+            float(rows(it).sum()))
+        registry.counter("search_slot_iters_total").inc(
+            float(it.max(initial=0)) * it.size)
     registry.counter("search_saturated_total").inc(
         float(rows(res.saturated).sum()))
-    fl = registry.histogram("search_final_l", buckets=DEFAULT_WORK_BUCKETS)
-    for v in rows(res.final_l).tolist():
-        fl.observe(float(v))
+    registry.histogram("search_final_l", buckets=DEFAULT_WORK_BUCKETS) \
+        .observe_many(rows(res.final_l))

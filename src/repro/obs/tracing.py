@@ -22,11 +22,19 @@ tracer.span(...)`` nests automatically and ``start_span(parent=...)``
 handles the cross-batch case where a child (batch) has many logical
 parents (the requests in it) — there, requests carry a ``link`` attribute
 listing the batch span instead, see ``ann_server.drain``.
+
+Every span that is not retroactive is also mirrored: it opens a
+``jax.profiler.TraceAnnotation`` of the same name, closed when the span
+ends, so a profile taken meanwhile shows the program's spans on the host
+timeline beside the device's operations (with no profile running, an
+annotation records nothing).  ``jax`` is imported at the first span, and
+where it cannot be, spans are not mirrored.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Optional
@@ -40,6 +48,9 @@ class Span:
     start: float                       # perf_counter seconds
     end: Optional[float] = None
     attrs: dict = dataclasses.field(default_factory=dict)
+    # the open profiler annotation of a mirrored span
+    annotation: object = dataclasses.field(default=None, repr=False,
+                                           compare=False)
 
     @property
     def duration_s(self) -> float:
@@ -61,6 +72,16 @@ class Span:
                 "attrs": dict(self.attrs)}
 
 
+@functools.cache
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 class Tracer:
     """Span factory + bounded ring of finished spans."""
 
@@ -72,16 +93,23 @@ class Tracer:
 
     # -- explicit API (non-lexical span lifetimes) ---------------------------
     def start_span(self, name: str, parent: Optional[Span] = None,
-                   **attrs) -> Span:
+                   start: Optional[float] = None, **attrs) -> Span:
         """Open a span.  ``parent`` wins over the implicit stack; pass
         ``parent=None`` explicitly via ``root=True`` semantics by not being
-        inside a ``with tracer.span(...)`` block."""
+        inside a ``with tracer.span(...)`` block.  ``start`` (a
+        ``perf_counter`` timestamp) opens a retroactive span, which is never
+        mirrored."""
         pid = parent.span_id if parent is not None else (
             self._stack[-1].span_id if self._stack else None)
         s = Span(name=name, span_id=self._next_id, parent_id=pid,
-                 start=time.perf_counter(), attrs=dict(attrs))
+                 start=time.perf_counter() if start is None else start,
+                 attrs=dict(attrs))
         self._next_id += 1
         self.n_started += 1
+        annotation = _trace_annotation() if start is None else None
+        if annotation is not None:
+            s.annotation = annotation(name)
+            s.annotation.__enter__()
         return s
 
     def end_span(self, span: Span, end: Optional[float] = None,
@@ -93,6 +121,9 @@ class Tracer:
             span.end = end if end is not None else time.perf_counter()
             span.attrs.update(attrs)
             self.finished.append(span)
+            if span.annotation is not None:
+                span.annotation.__exit__(None, None, None)
+                span.annotation = None
         return span
 
     def activate(self, span: Span) -> Span:

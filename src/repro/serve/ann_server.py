@@ -33,8 +33,11 @@ counters, batch-aggregated device counters (``n_dist_comps``/``n_hops``/…,
 the Exp-5 metrics at serve time) — and per-request spans (``serve.request``
 with a ``serve.queue_wait`` child) linked to per-batch spans
 (``serve.batch`` → ``serve.batch_form`` / ``serve.device_execute`` /
-``serve.merge``).  Both default to ``None`` = zero overhead, and enabling
+``serve.merge``; ``serve.device_execute`` → ``serve.put`` / ``serve.launch``
+/ ``serve.fetch``).  Both default to ``None`` = zero overhead, and enabling
 them cannot change results (pinned bit-identical in ``tests/test_obs.py``).
+Each batch's result comes back to the host in one read (``serve.fetch``),
+which the counters then aggregate without touching the device again.
 
 Single-process implementation (threads would add nothing in a test
 container); the ``submit_many`` / ``drain`` pair models the arrival loop so
@@ -43,9 +46,11 @@ benchmarks can replay request traces with arrival timestamps.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -74,7 +79,6 @@ class ServeStats:
     n_batches: int = 0
     total_latency_s: float = 0.0
     max_latency_s: float = 0.0
-    total_search_s: float = 0.0
     # -- resilience counters -------------------------------------------------
     n_rejected: int = 0          # failed per-request validation (shape/NaN/…)
     n_shed: int = 0              # refused by admission control (queue full)
@@ -87,10 +91,6 @@ class ServeStats:
     @property
     def mean_latency_s(self) -> float:
         return self.total_latency_s / max(self.n_requests, 1)
-
-    @property
-    def qps(self) -> float:
-        return self.n_requests / max(self.total_search_s, 1e-9)
 
 
 @dataclasses.dataclass
@@ -125,6 +125,9 @@ class AnnServer:
         self._seq = 0
         self.stats = ServeStats()
 
+    # the result fields a batch's answers read
+    ANSWER_FIELDS = ("ids", "dists")
+
     def _search(self, queries: jnp.ndarray,
                 params: Optional[SearchParams] = None,
                 engine: Optional[str] = None,
@@ -135,6 +138,24 @@ class AnnServer:
         remain addressable (the sharded subclass adds its own tiers)."""
         fn, args, kw = self._program(queries, params, engine, backend)
         return fn(*args, **kw)
+
+    def _execute(self, qs: np.ndarray, **overrides):
+        """One padded batch on the device: the queries in (``serve.put``),
+        the search call up to its return (``serve.launch``) and one blocking
+        read of the result (``serve.fetch``): the whole result where metrics
+        or spans observe it, else only ``ANSWER_FIELDS``.  Returns the result
+        with host arrays (unread fields ``None``); ``overrides`` go to
+        ``_search``."""
+        with self._span("serve.put"):
+            q = jnp.asarray(qs)
+        with self._span("serve.launch"):
+            res = self._search(q, **overrides)
+        with self._span("serve.fetch"):
+            if self.metrics is None and self.tracer is None:
+                res = dataclasses.replace(res, **{
+                    f.name: None for f in dataclasses.fields(res)
+                    if f.name not in self.ANSWER_FIELDS})
+            return jax.device_get(res)
 
     def _program(self, queries, params=None, engine=None, backend=None):
         """The jitted program one batch runs, with its arguments."""
@@ -156,6 +177,13 @@ class AnnServer:
         return fn.lower(*args, **kw).compile()
 
     # -- observability seams -------------------------------------------------
+    def _span(self, name: str, parent=None, **attrs):
+        """``with`` a tracer span (child of ``parent``, else of the span
+        open around it); nothing without a tracer."""
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.span(name, parent=parent, **attrs)
+
     def _obs_batch(self, n_live: int, res, exec_s: float) -> None:
         """Batch-level metrics: execute-time histogram, batch size, and the
         device-side work counters aggregated host-side (Exp-5 at serve
@@ -182,11 +210,10 @@ class AnnServer:
                     max(dispatch_t - req.wall_t, 0.0))
         if self.tracer is not None:
             rspan = self.tracer.start_span(
-                "serve.request", seq=req.seq, status=status,
+                "serve.request", start=req.wall_t, seq=req.seq, status=status,
                 batch=None if batch_span is None else batch_span.span_id)
-            rspan.start = req.wall_t
-            qspan = self.tracer.start_span("serve.queue_wait", parent=rspan)
-            qspan.start = req.wall_t
+            qspan = self.tracer.start_span("serve.queue_wait", parent=rspan,
+                                           start=req.wall_t)
             self.tracer.end_span(qspan, end=dispatch_t)
             self.tracer.end_span(rspan, end=done_t)
 
@@ -228,15 +255,12 @@ class AnnServer:
                 qs = np.concatenate([qs, np.repeat(qs[-1:], pad, axis=0)])
             if tr:
                 tr.end_span(fspan, size=len(take), bucket=bucket)
-            espan = tr.start_span("serve.device_execute", parent=bspan,
-                                  backend=self.backend) if tr else None
-            t0 = Timer.now()
-            res = self._search(jnp.asarray(qs))
-            ids = np.asarray(res.ids)
-            dists = np.asarray(res.dists)
-            t1 = Timer.now()
-            if tr:
-                tr.end_span(espan)
+            with self._span("serve.device_execute", parent=bspan,
+                            backend=self.backend):
+                t0 = Timer.now()
+                res = self._execute(qs)
+                t1 = Timer.now()
+            ids, dists = res.ids, res.dists
             self._obs_batch(len(take), res, t1 - t0)
             mspan = tr.start_span("serve.merge", parent=bspan) if tr else None
             for i, req in enumerate(take):
@@ -248,7 +272,12 @@ class AnnServer:
                 self._obs_response(req, t0, t1, "ok", batch_span=bspan)
             if tr:
                 tr.end_span(mspan)
-                tr.end_span(bspan, size=len(take))
+                tr.end_span(bspan, size=len(take), iters=batch_iters(res))
             self.stats.n_batches += 1
-            self.stats.total_search_s += t1 - t0
         return out
+
+
+def batch_iters(res) -> Optional[int]:
+    """A batch's lock-step iteration count: the largest ``n_iters`` over its
+    rows (``None`` where the result does not count them)."""
+    return None if res.n_iters is None else int(np.max(res.n_iters))

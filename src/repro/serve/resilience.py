@@ -66,7 +66,7 @@ import numpy as np
 from repro.core import EMQGIndex, SearchParams, SearchResult
 from repro.obs import Timer
 
-from .ann_server import AnnServer, _Request
+from .ann_server import AnnServer, _Request, batch_iters
 
 
 # ---------------------------------------------------------------------------
@@ -304,6 +304,8 @@ class ResilientAnnServer(AnnServer):
     one response per submitted request, crash-free by construction.
     """
 
+    ANSWER_FIELDS = ("ids", "dists", "saturated")
+
     def __init__(self, index, params: SearchParams, *,
                  config: ResilienceConfig = ResilienceConfig(),
                  clock=time.monotonic, **kw):
@@ -320,7 +322,7 @@ class ResilientAnnServer(AnnServer):
         self.rung = 0
         self._done: list[Response] = []
         self._last_tier: Optional[int] = None
-        self._last_result = None            # full SearchResult of last batch
+        self._last_result = None            # last batch's SearchResult (host)
         self._last_coverage: float = 1.0
         self._last_max_missed: int = 0
         self._compiled: set = set()         # (tier, params, shape) compiled
@@ -447,13 +449,11 @@ class ResilientAnnServer(AnnServer):
             tier_params = _tier_params(params, tier)
             self._compile_tier(qs, tier_params, tier)
             try:
-                res = self._search(jnp.asarray(qs), params=tier_params,
-                                   engine=tier.engine, backend=tier.backend)
-                out = (np.asarray(res.ids), np.asarray(res.dists),
-                       np.asarray(res.saturated))
+                res = self._execute(qs, params=tier_params,
+                                    engine=tier.engine, backend=tier.backend)
                 self.breaker.record_success(i)
-                self._last_result = res     # device counters for _obs_batch
-                return out, tier.name
+                self._last_result = res     # host counters for _obs_batch
+                return (res.ids, res.dists, res.saturated), tier.name
             except Exception as e:
                 last_err = e
                 self.breaker.record_failure(i)
@@ -534,7 +534,6 @@ class ResilientAnnServer(AnnServer):
                                         rung=rung, latency_s=t1 - req.wall_t,
                                         error=str(e)))
                 self.stats.n_batches += 1
-                self.stats.total_search_s += t1 - t0
                 if tr:
                     tr.end_span(bspan, size=len(live), status="failed")
                 continue
@@ -562,10 +561,10 @@ class ResilientAnnServer(AnnServer):
                     latency_s=lat, coverage=self._last_coverage,
                     max_missed=self._last_max_missed))
             self.stats.n_batches += 1
-            self.stats.total_search_s += t1 - t0
             if tr:
                 tr.end_span(mspan)
-                tr.end_span(bspan, size=len(live), tier=tier_name)
+                tr.end_span(bspan, size=len(live), tier=tier_name,
+                            iters=batch_iters(self._last_result))
         out.sort(key=lambda r: r.seq)
         return out
 

@@ -338,3 +338,84 @@ def test_server_rejects_unknown_engine(graph):
     params = SearchParams(k=5, l0=8, l_max=16)
     with pytest.raises(ValueError, match="unknown engine"):
         AnnServer(graph, params, engine="legacy")
+
+
+# ---------------------------------------------------------------------------
+# The lock-step counter and the hop-phase scopes.
+# ---------------------------------------------------------------------------
+
+HOP_SCOPES = ("hop.select", "hop.expand", "hop.visited", "hop.distance",
+              "hop.merge", "hop.transition")
+
+
+def _path_graph(n: int = 64):
+    """Nodes 0..n-1 on a line, each linked to its two neighbours."""
+    from repro.core import GraphIndex
+
+    nbrs = np.full((n, 2), -1, np.int32)
+    nbrs[1:, 0] = np.arange(n - 1)
+    nbrs[:-1, 1] = np.arange(1, n)
+    return GraphIndex(vectors=jnp.arange(n, dtype=jnp.float32)[:, None],
+                      neighbors=jnp.asarray(nbrs), medoid=jnp.int32(0))
+
+
+def test_n_iters_max_is_the_loop_trip_count(monkeypatch):
+    """Every query starts at node 0 of a path: the one at 60 walks about 60
+    hops, the others stop after a few.  The largest ``n_iters`` equals the
+    trips of a ``while_loop`` that counts its own."""
+    import jax
+
+    from repro.core.search import _beam_search_batch, make_batch_dist_fn
+
+    graph = _path_graph()
+    queries = jnp.asarray([[1.0], [2.0], [3.0], [60.0]])
+    p = SearchParams(k=1, l0=2, l_max=4, max_hops=512)
+    trips = []
+    real = jax.lax.while_loop
+
+    def counting(cond, body, init):
+        out, n = real(lambda c: cond(c[0]),
+                      lambda c: (body(c[0]), c[1] + 1), (init, jnp.int32(0)))
+        trips.append(int(n))
+        return out
+
+    monkeypatch.setattr(jax.lax, "while_loop", counting)
+    st = _beam_search_batch(graph, queries, jnp.zeros((4,), jnp.int32), p,
+                            make_batch_dist_fn(graph.vectors, "jnp"))
+    it = np.asarray(st.n_iters)
+    assert trips and it.max() == trips[0] > 50
+    assert it.min() < 10
+    # each row counts the iterations it was active in: one per expansion
+    # at W=1, plus the iteration in which its window ran dry
+    np.testing.assert_array_equal(it, np.asarray(st.n_hops) + 1)
+    monkeypatch.undo()
+    res = search(graph, queries, p, start=jnp.zeros((4,), jnp.int32),
+                 backend="jnp")
+    np.testing.assert_array_equal(np.asarray(res.n_iters), it)
+
+
+def test_compiled_search_has_every_hop_scope():
+    """The loop body's phases reach the compiled program as the op_name
+    metadata of its instructions, one scope each."""
+    import re
+
+    graph = _path_graph()
+    queries = jnp.zeros((8, 1), jnp.float32)
+    hlo = search.lower(graph, queries, SearchParams(k=1, l0=2, l_max=4),
+                       backend="jnp").compile().as_text()
+    found = {s for name in re.findall(r'op_name="([^"]*)"', hlo)
+             for s in name.split("/") if s.startswith("hop.")}
+    assert found == set(HOP_SCOPES)
+
+
+def test_compiled_probing_search_has_every_hop_scope(emqg):
+    """The probing engine's loop carries the same six scopes, and its
+    RaBitQ estimates their own, ``hop.estimate``."""
+    import re
+
+    queries = jnp.zeros((8, emqg.dim), jnp.float32)
+    hlo = probing_search.lower(emqg, queries, SearchParams(k=1, l0=2, l_max=4),
+                               backend="jnp").compile().as_text()
+    found = {s for name in re.findall(r'op_name="([^"]*)"', hlo)
+             for s in name.split("/") if s.startswith("hop.")}
+    assert found == set(HOP_SCOPES) | {"hop.estimate"}

@@ -5,9 +5,14 @@ The acceptance surface here is deliberately wide: the metric names are
 stable API (README §Observability), so the exporter tests grep for the
 exact families an operator's dashboards would scrape."""
 
+import dataclasses
+import glob
 import json
 import math
+import sys
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -79,6 +84,23 @@ def test_histogram_overflow_quantile_reports_observed_max():
     h.observe(5.0)
     h.observe(7.5)
     assert h.quantile(0.99) == 7.5            # not +Inf, not the edge
+
+
+def test_observe_many_matches_observe():
+    """The vectorised bucket count gives the histogram a loop of
+    ``observe`` gives: buckets, overflow, count, drops, min, max, sum."""
+    rng = np.random.default_rng(4)
+    vals = np.concatenate([rng.integers(1, 40000, 500).astype(float),
+                           [2.0, 4.0, 16384.0, 1e9, float("nan")]])
+    a, b = Histogram(DEFAULT_WORK_BUCKETS), Histogram(DEFAULT_WORK_BUCKETS)
+    for v in vals:
+        a.observe(v)
+    b.observe_many(vals)
+    assert (a.counts, a.overflow, a.count, a.n_dropped, a.min, a.max) == \
+        (b.counts, b.overflow, b.count, b.n_dropped, b.min, b.max)
+    assert a.sum == b.sum                     # integers: exact either way
+    b.observe_many(np.array([]))
+    assert b.count == a.count
 
 
 def test_histogram_nan_dropped_not_raised():
@@ -179,6 +201,44 @@ def test_explicit_parent_beats_stack_and_activate_bridges():
     inner = tr.start_span("fanout")
     tr.deactivate(root)
     assert inner.parent_id == root.span_id
+
+
+def test_mirrored_spans_on_profiler_host_plane(tmp_path):
+    """A mirrored span is an annotation on the host plane of a profiler
+    trace; a retroactive one (``start=`` given) is not."""
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("serve.batch"):
+            tr.end_span(tr.start_span("serve.put"))
+        tr.end_span(tr.start_span("serve.request", start=Timer.now()))
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(
+        glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)[0])
+    cpu = pd.find_plane_with_name("/host:CPU")
+    names = {e.name for line in cpu.lines for e in line.events}
+    assert {"serve.batch", "serve.put"} <= names
+    assert "serve.request" not in names
+    assert all(s.annotation is None for s in tr.finished)
+
+
+def test_spans_without_jax_are_not_mirrored(monkeypatch):
+    """Where ``jax.profiler`` cannot be imported, spans time as before and
+    carry no annotation."""
+    from repro.obs import tracing
+
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    tracing._trace_annotation.cache_clear()
+    try:
+        tr = Tracer()
+        with tr.span("serve.batch") as s:
+            assert s.annotation is None
+        assert tr.by_name("serve.batch")[0].finished
+    finally:
+        tracing._trace_annotation.cache_clear()
 
 
 def test_retroactive_end_and_ring_bound():
@@ -309,6 +369,51 @@ def test_ann_server_populates_taxonomy_and_spans(tiny):
             "serve.merge"} <= names
 
 
+def test_device_execute_split_and_batch_iters(tiny):
+    """``serve.device_execute`` holds the put, the launch and the fetch;
+    ``serve.batch`` carries the batch's lock-step iterations, and the
+    lock-step counters add up from them."""
+    m, tr = MetricsRegistry(), Tracer()
+    srv = AnnServer(tiny["graph"], PARAMS, max_batch=32, buckets=(32,),
+                    metrics=m, tracer=tr)
+    srv.submit_many(tiny["queries"])          # 48 → batches of 32 and 16
+    srv.drain()
+    for e in tr.by_name("serve.device_execute"):
+        kids = tr.children_of(e)
+        assert [k.name for k in kids] == ["serve.put", "serve.launch",
+                                          "serve.fetch"]
+        assert e.start <= kids[0].start and kids[-1].end <= e.end
+    iters = [b.attrs["iters"] for b in tr.by_name("serve.batch")]
+    assert len(iters) == 2 and min(iters) > 0
+    slots = m.counter("search_slot_iters_total").value
+    assert slots == 32 * iters[0] + 32 * iters[1]
+    rows = m.counter("search_row_iters_total").value
+    assert 0 < rows <= 32 * iters[0] + 16 * iters[1]
+
+
+def test_record_search_result_lockstep_counters():
+    """Live rows' iterations, and the trip count (pads included) times the
+    bucket's rows."""
+    from types import SimpleNamespace
+
+    from repro.obs import record_search_result
+
+    z = np.zeros(4, np.int32)
+    res = SimpleNamespace(n_dist_comps=z, n_hops=z, n_approx_comps=None,
+                          n_encounters=None, saturated=z,
+                          final_l=np.array([10, 12, 40, 40]),
+                          n_iters=np.array([3, 5, 2, 7], np.int32))
+    m = MetricsRegistry()
+    record_search_result(m, res, n_live=3)
+    assert m.counter("search_row_iters_total").value == 10
+    assert m.counter("search_slot_iters_total").value == 7 * 4
+    assert m.histogram("search_final_l", buckets=DEFAULT_WORK_BUCKETS) \
+        .count == 3
+    res.n_iters = None                        # a result made without the loop
+    record_search_result(m, res, n_live=3)
+    assert m.counter("search_slot_iters_total").value == 7 * 4
+
+
 def test_pad_rows_not_double_billed(tiny):
     """A 5-request batch padded to bucket 32 must aggregate device counters
     over 5 rows, not 32."""
@@ -326,7 +431,10 @@ def _ids_dists(out):
             np.stack([np.asarray(d) for _, d in out]))
 
 
-def test_metrics_on_vs_off_bit_identical_plain(tiny):
+def test_metrics_on_vs_off_bit_identical_plain(tiny, tmp_path):
+    """Metrics, spans and their mirror into a running profiler trace leave
+    every field of the result as it was, the lock-step counter included.
+    Unobserved, a batch fetches only the fields its answers read."""
     off = AnnServer(tiny["graph"], PARAMS, max_batch=32, buckets=(32,))
     on = AnnServer(tiny["graph"], PARAMS, max_batch=32, buckets=(32,),
                    metrics=declare_serve_metrics(MetricsRegistry()),
@@ -334,9 +442,28 @@ def test_metrics_on_vs_off_bit_identical_plain(tiny):
     off.submit_many(tiny["queries"])
     on.submit_many(tiny["queries"])
     ids0, d0 = _ids_dists(off.drain())
-    ids1, d1 = _ids_dists(on.drain())
+    qs = tiny["queries"][:32]
+    r0 = jax.device_get(off._search(jnp.asarray(qs)))
+    fetched = off._execute(qs)
+    for f in dataclasses.fields(fetched):
+        if f.name in AnnServer.ANSWER_FIELDS:
+            np.testing.assert_array_equal(getattr(fetched, f.name),
+                                          getattr(r0, f.name))
+        else:
+            assert getattr(fetched, f.name) is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ids1, d1 = _ids_dists(on.drain())
+        r1 = on._execute(qs)
+    finally:
+        jax.profiler.stop_trace()
     np.testing.assert_array_equal(ids0, ids1)
     np.testing.assert_array_equal(d0, d1)     # bit-identical, not allclose
+    for f in dataclasses.fields(r0):
+        np.testing.assert_array_equal(getattr(r0, f.name),
+                                      getattr(r1, f.name))
+    assert on.tracer.by_name("serve.batch")[0].attrs["iters"] \
+        == int(r0.n_iters.max())
 
 
 def test_metrics_on_vs_off_bit_identical_resilient(tiny):

@@ -98,11 +98,12 @@ def test_fused_estimate_compiles(one_chip):
     _assert_kernel(compiled)
 
 
-def test_probing_search_program_compiles(one_chip, monkeypatch):
-    """The whole served program: Algorithm 5 at B=256 with the exact tier on
-    ``gather_l2_tiled``.  This process's backend is the CPU, where the
-    kernel wrapper picks interpret mode; steer it to the compiled kernel."""
-    monkeypatch.setattr(l2ops, "_on_cpu", lambda: False)
+@pytest.fixture(scope="module")
+def probing_hlo(one_chip):
+    """The whole served δ-EMQG program, compiled once: Algorithm 5 at B=256
+    with the exact tier on ``gather_l2_tiled``.  This process's backend is
+    the CPU, where the kernel wrapper picks interpret mode; steer it to the
+    compiled kernel."""
     B = 256
     graph = GraphIndex(vectors=_sds((N, D), jnp.float32, one_chip),
                        neighbors=_sds((N, M), jnp.int32, one_chip),
@@ -115,8 +116,70 @@ def test_probing_search_program_compiles(one_chip, monkeypatch):
                         center=_sds((D,), jnp.float32, one_chip), dim=D)
     params = SearchParams(k=K, l0=K, l_max=512, alpha=1.2, adaptive=True,
                           max_hops=4096)
-    compiled = probing_search.lower(
-        EMQGIndex(graph=graph, codes=codes),
-        _sds((B, D), jnp.float32, one_chip), params,
-        backend="kernel_tiled").compile()
-    _assert_kernel(compiled)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(l2ops, "_on_cpu", lambda: False)
+        return probing_search.lower(
+            EMQGIndex(graph=graph, codes=codes),
+            _sds((B, D), jnp.float32, one_chip), params,
+            backend="kernel_tiled").compile().as_text()
+
+
+def test_probing_search_program_compiles(probing_hlo):
+    assert "tpu_custom_call" in probing_hlo
+
+
+def _loop_scopes(hlo: str):
+    """{scope} of the benchmark's scope map over every op of the compiled
+    program's loops, and the scopes of its ``gather_l2_tiled`` calls."""
+    import re
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
+    from harness import program_trace
+
+    comps = program_trace.parse(hlo)
+    scope_of = program_trace.scope_map(hlo)
+    loop = [ins for pair in re.findall(
+                r"condition=%?([\w.\-]+), body=%?([\w.\-]+)", hlo)
+            for c in pair for ins in comps[c]
+            if ins["op"] not in program_trace.INERT]
+    return ({scope_of.get(i["name"]) for i in loop},
+            {scope_of[i["name"]] for i in loop
+             if i["name"].startswith("gather_l2_tiled")})
+
+
+HOP_SCOPES = {"hop.select", "hop.expand", "hop.visited", "hop.distance",
+              "hop.merge", "hop.transition"}
+
+
+def test_search_program_phases_scoped(one_chip, monkeypatch):
+    """The served exact program (``search`` at the online batch, on
+    ``gather_l2_tiled``): as the chip's compiler emits it, every op of its
+    loop falls under one of the six ``hop.*`` named scopes in the
+    benchmark's scope map, the kernel under ``hop.distance``."""
+    from repro.core import search
+
+    monkeypatch.setattr(l2ops, "_on_cpu", lambda: False)
+    B = 256
+    graph = GraphIndex(vectors=_sds((N, D), jnp.float32, one_chip),
+                       neighbors=_sds((N, M), jnp.int32, one_chip),
+                       medoid=_sds((), jnp.int32, one_chip),
+                       kind="delta_emg", delta=0.2)
+    params = SearchParams(k=K, l0=K, l_max=512, alpha=1.2, adaptive=True,
+                          max_hops=4096)
+    hlo = search.lower(graph, _sds((B, D), jnp.float32, one_chip), params,
+                       backend="kernel_tiled").compile().as_text()
+    assert "tpu_custom_call" in hlo
+    scopes, kernel = _loop_scopes(hlo)
+    assert scopes == HOP_SCOPES
+    assert kernel == {"hop.distance"}
+
+
+def test_probing_program_phases_scoped(probing_hlo):
+    """The served δ-EMQG program as the chip's compiler emits it: every op
+    of its loops falls under the six scopes or ``hop.estimate`` (the RaBitQ
+    estimates), the exact kernel under ``hop.distance``."""
+    scopes, kernel = _loop_scopes(probing_hlo)
+    assert scopes == HOP_SCOPES | {"hop.estimate"}
+    assert kernel == {"hop.distance"}
