@@ -19,19 +19,30 @@ Two kernels, matching the two halves of a graph-search expansion:
                   per-neighbor loads; the wrapper clamps INVALID ids to row
                   0 and masks the output.
 
-``gather_l2_tiled`` — the beam-engine hot path.  The single-row variant
-                  issues one latency-bound DMA per grid step ((1, d) blocks);
-                  the tiled variant keeps the base matrix in HBM
-                  (``memory_space=ANY``), and each grid step launches
-                  ``block_rows`` row DMAs back-to-back into a VMEM scratch
-                  tile before a single vectorized (R, d) distance reduction —
-                  R in-flight copies amortize DMA issue latency and the
-                  compute runs on a full tile instead of one row.  VMEM per
-                  step is R·d·4 B (8×128 → 4 KiB) plus the (1, d) query line;
-                  the step's R row ids are blocked into SMEM.
+``gather_l2_tiled`` — the beam-engine hot path.  The base matrix stays in
+                  HBM (``memory_space=ANY``); one grid step covers a block
+                  of Q queries × all K slots (K padded to a multiple of 8,
+                  B to a multiple of Q; pad slots and queries read row 0
+                  and are dropped).  Q comes from the shape
+                  (``block_queries``): the largest multiple of 16 whose
+                  (Q, K, d) f32 row tile fits 512 KiB — Q=16 at K=64,
+                  d=128, so [1024, 64] ids take 64 steps.  The row copies
+                  overlap across steps: two VMEM row tiles and two DMA
+                  semaphores, and step i launches step i+1's Q·K row
+                  copies before it waits on its own, then reduces its
+                  (Q, K, d) tile on the VPU (f32 subtract, square, sum
+                  over d) into one (Q, K) output block.  The ids stay in
+                  HBM too and reach SMEM a step's block at a time, double
+                  buffered, two steps ahead of the rows.  VMEM: 2 × the
+                  row tile (1 MiB at K=64, d=128) plus the pipelined query
+                  and output blocks; SMEM: 2 × Q·K int32 (8 KiB).
+                  Operands stay (ids, base, queries), the queries' first
+                  dim B, so a call's work reads from its HLO shapes.
 
 ``gather_l2_tiled`` and the bitdot kernels compile for a TPU v5e
-(``tests/test_tpu_compile.py``).  ``batched_l2`` and ``gather_l2`` do not:
+(``tests/test_tpu_compile.py``), ``gather_l2_tiled`` at d ≤ 128 only: past
+one lane tile a base row is not contiguous in the (8, 128)-tiled HBM layout,
+and Mosaic refuses its one-row copy.  ``batched_l2`` and ``gather_l2`` do not:
 their (1, d) row blocks break the TPU tiling rule (the last two block dims
 must divide (8, 128) or equal the array's), and nothing on the serve path
 calls them.  All four are validated on CPU in interpret mode against
@@ -113,63 +124,111 @@ def gather_l2_pallas(base: jax.Array, ids: jax.Array, queries: jax.Array,
 
 # ---------------------------------------------------------------------------
 # gather_l2_tiled: base [n, d] + ids [B, K] + queries [B, d] → d2 [B, K],
-# R = block_rows gathered rows per grid step.
+# one grid step per block of Q queries × all K slots.
 # ---------------------------------------------------------------------------
 
-def _gather_l2_tiled_kernel(ids_ref, base_hbm, q_ref, out_ref, rows_vmem,
-                            sem, *, block_rows: int):
-    R = block_rows
-
-    def row_dma(r):
-        return pltpu.make_async_copy(
-            base_hbm.at[pl.ds(ids_ref[0, 0, 0, r], 1), :],
-            rows_vmem.at[pl.ds(r, 1), :],
-            sem,
-        )
-
-    # Launch all R row copies on one semaphore, then drain: R equal-sized
-    # DMAs in flight per grid step.
-    for r in range(R):
-        row_dma(r).start()
-    for r in range(R):
-        row_dma(r).wait()
-
-    diff = rows_vmem[...] - q_ref[0]
-    out_ref[0, 0, 0, :] = jnp.sum(diff * diff, axis=1)
+_SUBLANE = 8
+_QUERY_STEP = 16                  # Q·K ids (K a multiple of 8): whole lanes
+_ROW_TILE_BYTES = 512 * 1024      # one step's gathered rows, per buffer
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def block_queries(B: int, K: int, d: int) -> int:
+    """Queries per grid step for ids [B, K] into rows of d f32 (K a multiple
+    of 8): the largest multiple of 16 whose (Q, K, d) row tile fits
+    ``_ROW_TILE_BYTES``, at least 16, and no more than B rounded up to 16.
+    A multiple of 16 makes a step's Q·K ids whole 128-lane rows, as their
+    copy into SMEM requires."""
+    fit = _ROW_TILE_BYTES // (K * d * 4) // _QUERY_STEP * _QUERY_STEP
+    return min(max(fit, _QUERY_STEP), pl.cdiv(B, _QUERY_STEP) * _QUERY_STEP)
+
+
+def _gather_l2_tiled_kernel(ids_hbm, base_hbm, q_ref, out_ref, ids_smem,
+                            rows_vmem, ids_sem, rows_sem, *, n_queries: int,
+                            n_slots: int, n_steps: int):
+    Q, K = n_queries, n_slots
+    i = pl.program_id(0)
+    slot = i % 2
+
+    def ids_copy(step, s):
+        return pltpu.make_async_copy(ids_hbm.at[step], ids_smem.at[s],
+                                     ids_sem.at[s])
+
+    def row_copy(s, j, r, row):
+        return pltpu.make_async_copy(base_hbm.at[pl.ds(row, 1), :],
+                                     rows_vmem.at[s, j, pl.ds(r, 1), :],
+                                     rows_sem.at[s])
+
+    def start_rows(s):
+        """Launch the Q·K row copies of the step whose ids sit in slot s."""
+        def per_query(j, carry):
+            for r in range(K):
+                row_copy(s, j, r, ids_smem[s, 0, j * K + r]).start()
+            return carry
+        jax.lax.fori_loop(0, Q, per_query, 0)
+
+    # Ids run two steps ahead of the rows, the rows one step ahead of the
+    # arithmetic: step i launches step i+1's row copies (its ids arrived
+    # during step i-1) and step i+2's ids before it waits on its own rows.
+    @pl.when(i == 0)
+    def _():
+        first = ids_copy(0, 0)
+        first.start()
+        first.wait()
+        start_rows(0)
+        if n_steps > 1:
+            ids_copy(1, 1).start()
+
+    @pl.when(i + 1 < n_steps)
+    def _():
+        ids_copy(i + 1, 1 - slot).wait()
+        start_rows(1 - slot)
+
+        @pl.when(i + 2 < n_steps)
+        def _():
+            ids_copy(i + 2, slot).start()
+
+    # A wait reads only the copy's size and semaphore: row 0 stands in.
+    def wait_query(j, carry):
+        for r in range(K):
+            row_copy(slot, j, r, 0).wait()
+        return carry
+    jax.lax.fori_loop(0, Q, wait_query, 0)
+
+    diff = rows_vmem[slot] - q_ref[...][:, None, :]
+    out_ref[...] = jnp.sum(diff * diff, axis=2)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_l2_tiled_pallas(base: jax.Array, ids: jax.Array, queries: jax.Array,
-                           block_rows: int = 8,
                            interpret: bool = False) -> jax.Array:
     B, K = ids.shape
-    n, d = base.shape
-    if K % block_rows:
-        raise ValueError(f"K={K} must be a multiple of block_rows={block_rows}"
-                         " (wrapper pads)")
-    T = K // block_rows
-    # Every block's last two dims equal its array's (the TPU tiling rule):
-    # ids and the output get a unit axis before their R-wide row, the query
-    # one before its d-wide row.  ids are blocked per grid step into SMEM
-    # rather than scalar-prefetched whole: ids[B, K] at B=4096 outgrows the
-    # 1 MiB SMEM.
+    d = base.shape[1]
+    Kp = pl.cdiv(K, _SUBLANE) * _SUBLANE
+    Q = block_queries(B, Kp, d)
+    Bp = pl.cdiv(B, Q) * Q
+    steps = Bp // Q
+    # Pad slots and queries read row 0; their outputs are dropped below.
+    ids = jnp.pad(ids.astype(jnp.int32), ((0, Bp - B), (0, Kp - K)))
+    queries = jnp.pad(queries.astype(jnp.float32), ((0, Bp - B), (0, 0)))
     out = pl.pallas_call(
-        functools.partial(_gather_l2_tiled_kernel, block_rows=block_rows),
-        grid=(B, T),
+        functools.partial(_gather_l2_tiled_kernel, n_queries=Q, n_slots=Kp,
+                          n_steps=steps),
+        grid=(steps,),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, block_rows), lambda b, t: (b, t, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),              # base stays in HBM
-            pl.BlockSpec((1, 1, d), lambda b, t: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),      # ids, copied per step
+            pl.BlockSpec(memory_space=pl.ANY),      # base stays in HBM
+            pl.BlockSpec((Q, d), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, block_rows),
-                               lambda b, t: (b, t, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, T, 1, block_rows), jnp.float32),
+        out_specs=pl.BlockSpec((Q, Kp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, Kp), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((block_rows, d), jnp.float32),
-            pltpu.SemaphoreType.DMA(()),
+            pltpu.SMEM((2, 1, Q * Kp), jnp.int32),
+            pltpu.VMEM((2, Q, Kp, d), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(ids.astype(jnp.int32).reshape(B, T, 1, block_rows),
-      base.astype(jnp.float32), queries.astype(jnp.float32)[:, None, :])
-    return out.reshape(B, K)
+    )(ids.reshape(steps, 1, Q * Kp), base.astype(jnp.float32), queries)
+    return out[:B, :K]

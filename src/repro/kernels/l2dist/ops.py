@@ -58,27 +58,21 @@ def gather_l2(base: jax.Array, ids: jax.Array, queries: jax.Array,
     return jnp.where(ids >= 0, d2, jnp.inf)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block_rows", "use_ref", "interpret"))
+@functools.partial(jax.jit, static_argnames=("use_ref", "interpret"))
 def gather_l2_tiled(base: jax.Array, ids: jax.Array, queries: jax.Array,
-                    block_rows: int = 8, use_ref: bool = False,
+                    use_ref: bool = False,
                     interpret: bool | None = None) -> jax.Array:
-    """Tiled fused gather+L2: ``block_rows`` row DMAs per grid step.
+    """Tiled fused gather+L2: one grid step per block of queries.
 
-    Same contract as :func:`gather_l2`; M is padded up to a multiple of
-    ``block_rows`` internally (pad rows index row 0 and are masked out).
+    Same contract as :func:`gather_l2`; the block comes from the shapes
+    (``l2dist.block_queries``), and M and B are padded internally (pad
+    slots and pad queries read row 0 and are dropped).
     """
-    B, M = ids.shape
     safe = jnp.maximum(ids, 0)
     if use_ref:
         d2 = ref.gather_l2_ref(base, safe, queries)
     else:
         interp = _on_cpu() if interpret is None else interpret
-        pad = (-M) % block_rows
-        if pad:
-            safe = jnp.pad(safe, ((0, 0), (0, pad)))
         d2 = gather_l2_tiled_pallas(_pad_lane(base, 1), safe,
-                                    _pad_lane(queries, 1),
-                                    block_rows=block_rows, interpret=interp)
-        d2 = d2[:, :M]
+                                    _pad_lane(queries, 1), interpret=interp)
     return jnp.where(ids >= 0, d2, jnp.inf)

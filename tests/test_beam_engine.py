@@ -281,20 +281,28 @@ def test_unique_per_row():
 # Tiled gather kernel.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B,M,d,R", [(2, 16, 24, 8), (4, 30, 128, 8),
-                                     (1, 7, 65, 4), (3, 24, 33, 8)])
-def test_gather_l2_tiled_vs_ref(B, M, d, R):
+@pytest.mark.parametrize("B,M,d", [
+    (2, 16, 24), (4, 30, 128), (1, 7, 65), (3, 24, 33),
+    # the shape-derived query block: B never a multiple of it, K from the
+    # start call's 1 to W=4's 256, d below, off and at the lane width
+    (1, 1, 24), (3, 64, 65), (9, 256, 128), (257, 64, 128), (257, 1, 24),
+    (9, 64, 24), (1, 64, 65), (3, 1, 128)])
+def test_gather_l2_tiled_vs_ref(B, M, d):
     rng = np.random.default_rng(B * 100 + M + d)
     n = 200
     base = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     ids = rng.integers(0, n, (B, M)).astype(np.int32)
-    ids[0, 0] = -1                      # INVALID handling
+    ids[0, 0] = -1                      # INVALID handling: first slot,
+    ids[-1, -1] = -1                    # last slot,
+    if B > 2:
+        ids[B // 2] = -1                # and a wholly INVALID row
     ids = jnp.asarray(ids)
     qs = jnp.asarray(rng.normal(size=(B, d)).astype(np.float32))
-    out = np.asarray(gather_l2_tiled(base, ids, qs, block_rows=R))
+    out = np.asarray(gather_l2_tiled(base, ids, qs))
     expect = np.asarray(l2ref.gather_l2_ref(base, jnp.maximum(ids, 0), qs))
-    assert np.isinf(out[0, 0])
+    assert out.shape == (B, M)
     mask = np.asarray(ids) >= 0
+    assert np.isinf(out[~mask]).all()
     np.testing.assert_allclose(out[mask], expect[mask], rtol=1e-4, atol=1e-3)
 
 

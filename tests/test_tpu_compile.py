@@ -66,15 +66,24 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("B", [256, 4096])
+def _compile_gather(sharding, B, K):
+    return l2ops.gather_l2_tiled.lower(
+        _sds((N, D), jnp.float32, sharding),
+        _sds((B, K), jnp.int32, sharding),
+        _sds((B, D), jnp.float32, sharding),
+        interpret=False).compile()
+
+
+@pytest.mark.parametrize("B", [256, 1024, 4096])
 @pytest.mark.parametrize("W", [1, 4])
 def test_gather_l2_tiled_compiles(one_chip, B, W):
-    compiled = l2ops.gather_l2_tiled.lower(
-        _sds((N, D), jnp.float32, one_chip),
-        _sds((B, W * M), jnp.int32, one_chip),
-        _sds((B, D), jnp.float32, one_chip),
-        interpret=False).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(_compile_gather(one_chip, B, W * M))
+
+
+@pytest.mark.parametrize("B", [256, 1024])
+def test_gather_l2_tiled_start_call_compiles(one_chip, B):
+    """The per-batch start call: one id per query, padded to 8 slots."""
+    _assert_kernel(_compile_gather(one_chip, B, 1))
 
 
 def test_bitdot_compiles(one_chip):
@@ -183,3 +192,42 @@ def test_probing_program_phases_scoped(probing_hlo):
     scopes, kernel = _loop_scopes(probing_hlo)
     assert scopes == HOP_SCOPES | {"hop.estimate"}
     assert kernel == {"hop.distance"}
+
+
+def test_search_kernel_calls_read_as_all_rows(one_chip, monkeypatch):
+    """The bulk batch's program (``search`` at B=1,024): every
+    ``gather_l2_tiled`` call in it, found by the benchmark's custom-call
+    reader, has three operands (ids, the corpus, the queries) and is
+    charged B·K′ gathered rows of d floats — the start call's [B, 1] ids
+    padded to 8 slots, the loop's [B, 64] — so the kernel's roofline reads
+    the same work whatever the kernel's blocking."""
+    import re
+    import sys
+    from pathlib import Path
+
+    from repro.core import search
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
+    from harness import kernels
+
+    monkeypatch.setattr(l2ops, "_on_cpu", lambda: False)
+    B = 1024
+    graph = GraphIndex(vectors=_sds((N, D), jnp.float32, one_chip),
+                       neighbors=_sds((N, M), jnp.int32, one_chip),
+                       medoid=_sds((), jnp.int32, one_chip),
+                       kind="delta_emg", delta=0.2)
+    params = SearchParams(k=K, l0=K, l_max=512, alpha=1.2, adaptive=True,
+                          max_hops=4096)
+    hlo = search.lower(graph, _sds((B, D), jnp.float32, one_chip), params,
+                       backend="kernel_tiled").compile().as_text()
+    calls = kernels.custom_calls(hlo, "gather_l2_tiled")
+    named = re.findall(r"^\s*%?(gather_l2_tiled[\w.\-]*) = \S+ custom-call\(",
+                       hlo, re.M)
+    assert calls and sorted(calls) == sorted(named)
+    rows = set()
+    for operands in calls.values():
+        (ids_t, _), (base_t, base), (q_t, q) = operands
+        assert (ids_t, base_t, q_t) == ("s32", "f32", "f32")
+        assert base == (N, D) and q[0] == B
+        rows.add(kernels.gather_l2_tiled_work(operands)["flops"] // (3 * D))
+    assert rows == {B * 8, B * M}
