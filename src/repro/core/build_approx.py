@@ -13,7 +13,8 @@ Faithful to the paper:
   * per-node candidates from greedy search            (line 6)
   * LocallySelectNeighbors with δ_t(u,v) = 1 − d(u,v)/d(u,v_(t))   (line 21)
   * degree cap M, reverse edges, connectivity repair  (lines 8–15)
-  * optional degree alignment for δ-EMQG (binary search on t, Sec. 6.1)
+  * optional degree alignment for δ-EMQG (Sec. 6.1): short rows are filled
+    up to M; with a fixed δ they keep every edge they had
 """
 
 from __future__ import annotations
@@ -62,7 +63,7 @@ class BuildParams:
     iters: int = 3                # refinement iterations I
     delta: Optional[float] = None  # None → adaptive δ_t rule; float → fixed δ (Exp-3)
     rule: str = "delta_emg"
-    align_degree: bool = False    # δ-EMQG: binary-search t so |N(u)| == M exactly
+    align_degree: bool = False    # δ-EMQG: fill every row up to M (Sec. 6.1)
     block: int = 512              # nodes per device batch
     max_hops: int = 1024
     seed: int = 0
@@ -237,45 +238,65 @@ def _prep_candidates(vectors, u_ids, merged_ids, L: int):
     return jax.vmap(one)(u_ids, merged_ids)
 
 
-def _align_degrees(vectors, nbr, deg, cand_ids_all, cand_dists_all, p: BuildParams):
-    """Sec. 6.1: binary-search the smallest t whose pruned neighborhood has
-    ≥ M entries, then keep the M closest → every node has exactly M
-    neighbors (FastScan / lane alignment)."""
-    n, M, L = nbr.shape[0], p.max_degree, p.beam_width
-    deficient = np.where(deg < M)[0]
+def _align_degrees(vectors, nbr, deg, cand_ids_all, cand_dists_all,
+                   p: BuildParams) -> None:
+    """Sec. 6.1: bring every row with fewer than M neighbours up to M
+    (FastScan / lane alignment), in place on ``nbr`` and ``deg``.
+
+    With a fixed ``p.delta`` a row keeps every edge the last refinement
+    iteration left in it (the δ-selection, the reverse edges and the
+    connectivity repair) and its free slots take the node's nearest
+    unselected candidates: a δ-monotone greedy step moves to the nearest
+    neighbour, and added out-edges can only offer a closer one.  With the
+    adaptive δ_t rule the row is re-selected at the smallest t that keeps at
+    least M (a binary search on t) and then padded the same way; that drops
+    the row's reverse and repair edges, and the repair that follows the
+    alignment re-links any node left unreachable.  A row whose candidates
+    run out stays short."""
+    deficient = np.where(deg < p.max_degree)[0]
     for s in range(0, deficient.size, p.block):
         idx = deficient[s : s + p.block]
-        ids = jnp.asarray(cand_ids_all[idx])
-        dst = jnp.asarray(cand_dists_all[idx])
-        u_ids = jnp.asarray(idx.astype(np.int32))
-        lo = np.full(idx.size, 1, np.int32)
-        hi = np.full(idx.size, L, np.int32)
-        n_cand = (cand_ids_all[idx] >= 0).sum(1)
-        # nodes with fewer than M candidates can never reach M — take all
-        feasible = n_cand >= M + 1
-        best = hi.copy()
-        for _ in range(int(np.ceil(np.log2(max(L, 2)))) + 1):
-            mid = (lo + hi) // 2
-            _, cnt = _select_block_per_node_t(
-                vectors, u_ids, ids, dst, jnp.asarray(mid),
-                rule=p.rule, max_keep=M + 1,
-            )
-            cnt = np.asarray(cnt)
-            enough = cnt >= M
-            best = np.where(enough & (mid < best), mid, best)
-            hi = np.where(enough, np.maximum(mid - 1, 1), hi)
-            lo = np.where(enough, lo, np.minimum(mid + 1, L))
-            if (lo > hi).all():
-                break
-        t_final = np.where(feasible, best, L).astype(np.int32)
-        kept, cnt = _select_block_per_node_t(
-            vectors, u_ids, ids, dst, jnp.asarray(t_final),
-            rule=p.rule, max_keep=M,
-        )
-        kept, cnt = np.array(kept), np.array(cnt)
-        _pad_from_pool(kept, cnt, cand_ids_all[idx], idx)
-        nbr[idx] = kept
+        if p.delta is None:
+            rows, cnt = _reselect_adaptive_t(
+                vectors, idx, cand_ids_all[idx], cand_dists_all[idx], p)
+        else:
+            rows, cnt = nbr[idx], deg[idx]
+        _pad_from_pool(rows, cnt, cand_ids_all[idx], idx)
+        nbr[idx] = rows
         deg[idx] = cnt
+
+
+def _reselect_adaptive_t(vectors, idx, cand_ids, cand_dists,
+                         p: BuildParams):
+    """The adaptive-δ_t neighbourhoods of nodes ``idx`` at the smallest t
+    whose selection keeps at least M (all L where the pool is too small):
+    int32[len(idx), M] rows, -1 padded, and their int32 counts."""
+    M, L = p.max_degree, p.beam_width
+    ids, dst = jnp.asarray(cand_ids), jnp.asarray(cand_dists)
+    u_ids = jnp.asarray(idx.astype(np.int32))
+    lo = np.full(idx.size, 1, np.int32)
+    hi = np.full(idx.size, L, np.int32)
+    # nodes with fewer than M candidates can never reach M — take all
+    feasible = (cand_ids >= 0).sum(1) >= M + 1
+    best = hi.copy()
+    for _ in range(int(np.ceil(np.log2(max(L, 2)))) + 1):
+        mid = (lo + hi) // 2
+        _, cnt = _select_block_per_node_t(
+            vectors, u_ids, ids, dst, jnp.asarray(mid),
+            rule=p.rule, max_keep=M + 1,
+        )
+        enough = np.asarray(cnt) >= M
+        best = np.where(enough & (mid < best), mid, best)
+        hi = np.where(enough, np.maximum(mid - 1, 1), hi)
+        lo = np.where(enough, lo, np.minimum(mid + 1, L))
+        if (lo > hi).all():
+            break
+    t_final = np.where(feasible, best, L).astype(np.int32)
+    kept, cnt = _select_block_per_node_t(
+        vectors, u_ids, ids, dst, jnp.asarray(t_final),
+        rule=p.rule, max_keep=M,
+    )
+    return np.array(kept), np.array(cnt)
 
 
 def _pad_from_pool(kept: np.ndarray, cnt: np.ndarray, pool: np.ndarray,
@@ -393,12 +414,15 @@ def build_approx(vectors, params: BuildParams = BuildParams(),
     if p.align_degree:
         t0 = time.perf_counter()
         deg = (np.asarray(graph.neighbors) >= 0).sum(1).astype(np.int32)
+        deficient, before = int((deg < M).sum()), int(deg.sum())
         nbr = np.asarray(graph.neighbors).copy()
         _align_degrees(vectors, nbr, deg, cand_ids_all, cand_dists_all, p)
+        # nothing to do after a fixed-δ alignment, which only adds edges
         _repair_connectivity(vectors_np, nbr, deg, M, med)
         graph = GraphIndex(vectors, jnp.asarray(nbr), jnp.int32(med),
                            kind="delta_emqg", delta=p.delta or 0.0)
         elapsed = time.perf_counter() - t0
         _build_event(metrics, verbose, "align_degree", nodes=n,
-                     elapsed_s=elapsed, nodes_per_s=n / max(elapsed, 1e-9))
+                     elapsed_s=elapsed, nodes_per_s=n / max(elapsed, 1e-9),
+                     deficient=deficient, padded=int(deg.sum()) - before)
     return graph

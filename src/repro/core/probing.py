@@ -15,10 +15,14 @@ over ``[B, W]`` ids) or *expands* its W best unvisited exact candidates
 distances in one batched RaBitQ estimate).  The NeedProbing rule
 (lines 22-28) decides per query; finished queries are masked no-ops.
 
-Fixed-shape state:
-
-  C_e — exact candidates  (ids, exact d², visited flags)   cap l_max+1
-  C_a — approx candidates (ids, approx d², probed flags)   cap l_max+1
+Fixed-shape state: one candidate list of capacity ``l_max+1`` holds both
+tiers, C_e (exact d², expanded or not) and C_a (RaBitQ estimates, not yet
+probed), each entry ranked by the distance it has.  The window of Alg. 3 is
+the list's first ``l`` entries: a probe takes its candidate out of the list
+and merges it back at its exact distance, so a probed candidate never holds
+a window slot by its estimate.  The loop ends for a query when its window
+holds only expanded exact candidates, and the answer is the exact tier's
+first ``k``.
 
 Also provides AGS (approximate greedy search + exact rerank — SymphonyQG's
 search, the paper's δ-EMQG-AGS ablation), built on the same batch engine:
@@ -44,10 +48,10 @@ from .bitset import bitset_make, bitset_set, bitset_test, unique_per_row
 from .search import (
     _beam_search_batch,
     adaptive_transition,
-    batch_merge_topc,
     make_batch_dist_fn,
     resolve_beam_width,
     select_top_w,
+    sort_rows,
 )
 from .types import INVALID_ID, EMQGIndex, SearchParams, SearchResult
 
@@ -57,14 +61,17 @@ from .types import INVALID_ID, EMQGIndex, SearchParams, SearchResult
 # ---------------------------------------------------------------------------
 
 
+# Tiers of a candidate-list entry.
+_APPROX = 0     # RaBitQ estimate only: a probe may promote it
+_EXACT = 1      # exact distance, not yet expanded
+_EXPANDED = 2   # exact distance, neighbours walked
+
+
 class _BeamPState(NamedTuple):
-    ce_ids: jax.Array      # int32[B, C]  exact tier
-    ce_d2: jax.Array       # f32[B, C]
-    ce_vis: jax.Array      # bool[B, C]
-    ca_ids: jax.Array      # int32[B, C]  approx tier
-    ca_d2: jax.Array       # f32[B, C]
-    ca_prb: jax.Array      # bool[B, C]
-    seen: jax.Array        # uint32[B, nw] every id that entered either tier
+    ids: jax.Array         # int32[B, C]  candidates of both tiers, by d2
+    d2: jax.Array          # f32[B, C]    exact d² or RaBitQ estimate
+    tier: jax.Array        # int8[B, C]   _APPROX | _EXACT | _EXPANDED
+    seen: jax.Array        # uint32[B, nw] every id that entered the list
     d2_last: jax.Array     # f32[B]  exact d² of the last expanded node
     l: jax.Array           # int32[B]
     n_dist: jax.Array      # int32[B]
@@ -95,12 +102,9 @@ def _beam_probing_batch(
 
     d2_s = batch_exact(queries, start[:, None])[:, 0]
     st = _BeamPState(
-        ce_ids=jnp.full((B, C), INVALID_ID, jnp.int32).at[:, 0].set(start),
-        ce_d2=jnp.full((B, C), jnp.inf, jnp.float32).at[:, 0].set(d2_s),
-        ce_vis=jnp.zeros((B, C), jnp.bool_),
-        ca_ids=jnp.full((B, C), INVALID_ID, jnp.int32),
-        ca_d2=jnp.full((B, C), jnp.inf, jnp.float32),
-        ca_prb=jnp.zeros((B, C), jnp.bool_),
+        ids=jnp.full((B, C), INVALID_ID, jnp.int32).at[:, 0].set(start),
+        d2=jnp.full((B, C), jnp.inf, jnp.float32).at[:, 0].set(d2_s),
+        tier=jnp.full((B, C), _APPROX, jnp.int8).at[:, 0].set(_EXACT),
         seen=bitset_set(bitset_make(B, n_nodes), start[:, None]),
         d2_last=d2_s,
         l=jnp.full((B,), min(max(p.l0, p.k), p.l_max), jnp.int32),
@@ -125,15 +129,18 @@ def _beam_probing_batch(
 
     def body(s: _BeamPState) -> _BeamPState:
         with jax.named_scope("hop.select"):
+            # The window is the first l entries of one list that ranks
+            # exact candidates by their distance and unprobed ones by their
+            # estimate; a probed entry leaves its estimate's rank and
+            # re-enters at its exact distance's.
             active = active_mask(s)
-            win_e = (pos < s.l[:, None]) & (s.ce_ids >= 0) & (~s.ce_vis)
-            win_e &= active[:, None]
-            win_a = (pos < s.l[:, None]) & (s.ca_ids >= 0) & (~s.ca_prb)
-            win_a &= active[:, None]
+            win = (pos < s.l[:, None]) & (s.ids >= 0) & active[:, None]
+            win_e = win & (s.tier == _EXACT)
+            win_a = win & (s.tier == _APPROX)
             has_u = jnp.any(win_e, axis=1)
             has_w = jnp.any(win_a, axis=1)
-            d2_u = jnp.min(jnp.where(win_e, s.ce_d2, jnp.inf), axis=1)
-            d2_w = jnp.min(jnp.where(win_a, s.ca_d2, jnp.inf), axis=1)
+            d2_u = jnp.min(jnp.where(win_e, s.d2, jnp.inf), axis=1)
+            d2_w = jnp.min(jnp.where(win_a, s.d2, jnp.inf), axis=1)
 
             # NeedProbing (lines 22-28): probe when the exact frontier
             # stopped improving and the approx tier has something closer.
@@ -146,25 +153,29 @@ def _beam_probing_batch(
             expanding = active & ~need_probe & has_u
             conv = active & ~has_u & ~has_w
 
-            # -- probe branch: the W best unprobed approx candidates ---------
-            sel_w, selv_w = select_top_w(s.ca_d2, win_a, W)
+            # -- probe branch: the W best unprobed candidates, taken out of
+            #    the list; the merge puts them back at their exact distance
+            sel_w, selv_w = select_top_w(s.d2, win_a, W)
             selv_w &= probing[:, None]
-            prb_sel = jnp.take_along_axis(s.ca_prb, sel_w, axis=1) | selv_w
-            ca_prb = s.ca_prb.at[rows, sel_w].set(prb_sel)
-            w_ids = jnp.where(
-                selv_w, jnp.take_along_axis(s.ca_ids, sel_w, axis=1),
-                INVALID_ID)
+            picked = jnp.take_along_axis(s.ids, sel_w, axis=1)
+            w_ids = jnp.where(selv_w, picked, INVALID_ID)
+            ids = s.ids.at[rows, sel_w].set(
+                jnp.where(selv_w, INVALID_ID, picked))
+            d2 = s.d2.at[rows, sel_w].set(jnp.where(
+                selv_w, jnp.inf, jnp.take_along_axis(s.d2, sel_w, axis=1)))
 
             # -- expand branch: the W best unexpanded exact candidates -------
-            sel_u, selv_u = select_top_w(s.ce_d2, win_e, W)
+            sel_u, selv_u = select_top_w(s.d2, win_e, W)
             selv_u &= expanding[:, None]
-            vis_sel = jnp.take_along_axis(s.ce_vis, sel_u, axis=1) | selv_u
-            ce_vis = s.ce_vis.at[rows, sel_u].set(vis_sel)
+            tier_sel = jnp.where(
+                selv_u, jnp.int8(_EXPANDED),
+                jnp.take_along_axis(s.tier, sel_u, axis=1))
+            tier = s.tier.at[rows, sel_u].set(tier_sel)
             u_ids = jnp.where(
-                selv_u, jnp.take_along_axis(s.ce_ids, sel_u, axis=1),
+                selv_u, jnp.take_along_axis(s.ids, sel_u, axis=1),
                 INVALID_ID)
             d2_u_sel = jnp.where(
-                selv_u, jnp.take_along_axis(s.ce_d2, sel_u, axis=1), -jnp.inf)
+                selv_u, jnp.take_along_axis(s.d2, sel_u, axis=1), -jnp.inf)
             # "last expanded" = the worst of this hop's frontier (W=1: u).
             d2_last = jnp.where(expanding, jnp.max(d2_u_sel, axis=1),
                                 s.d2_last)
@@ -193,27 +204,28 @@ def _beam_probing_batch(
             n_approx = s.n_approx \
                 + jnp.sum(new_ids >= 0, axis=1).astype(jnp.int32)
 
-        # -- merges (per query only one branch contributes real entries) -----
+        # -- one merge: the list, the probed ids at their exact distances
+        #    (per query only one of the two new blocks holds real entries)
         with jax.named_scope("hop.merge"):
-            ce_ids, ce_d2, ce_vis = batch_merge_topc(
-                s.ce_ids, s.ce_d2, ce_vis,
-                w_ids, d2_probe, jnp.zeros_like(w_ids, jnp.bool_), C)
-            ca_ids, ca_d2, ca_prb = batch_merge_topc(
-                s.ca_ids, s.ca_d2, ca_prb,
-                new_ids, d2a, jnp.zeros_like(fresh), C)
+            d2, ids, tier = sort_rows(
+                jnp.concatenate([d2, d2_probe, d2a], axis=1),
+                jnp.concatenate([ids, w_ids, new_ids], axis=1),
+                jnp.concatenate([tier, jnp.full_like(w_ids, _EXACT, jnp.int8),
+                                 jnp.full_like(new_ids, _APPROX, jnp.int8)],
+                                axis=1))
+            ids, d2, tier = ids[:, :C], d2[:, :C], tier[:, :C]
 
-        # -- adaptive transition for exhausted queries -----------------------
+        # -- adaptive transition for exhausted queries: every valid entry of
+        #    their window is expanded, so C[l] and C[k] are exact ----------
         with jax.named_scope("hop.transition"):
             l, done, saturated = adaptive_transition(
-                p, ce_d2, s.l, s.done, s.saturated, conv)
+                p, d2, s.l, s.done, s.saturated, conv)
             n_iters = s.n_iters + active.astype(jnp.int32)
 
         return _BeamPState(
-            ce_ids=ce_ids, ce_d2=ce_d2, ce_vis=ce_vis,
-            ca_ids=ca_ids, ca_d2=ca_d2, ca_prb=ca_prb,
-            seen=seen, d2_last=d2_last, l=l, n_dist=n_dist,
-            n_approx=n_approx, n_enc=n_enc, n_hops=n_hops, n_iters=n_iters,
-            done=done, saturated=saturated)
+            ids=ids, d2=d2, tier=tier, seen=seen, d2_last=d2_last, l=l,
+            n_dist=n_dist, n_approx=n_approx, n_enc=n_enc, n_hops=n_hops,
+            n_iters=n_iters, done=done, saturated=saturated)
 
     return jax.lax.while_loop(cond, body, st)
 
@@ -251,10 +263,15 @@ def probing_search(
 
     st = _beam_probing_batch(g.neighbors, g.n, batch_exact, batch_approx,
                              queries, start, params)
+    # the exact tier, in order: an answer never carries an estimate (a row
+    # cut off by ``max_hops`` may hold unprobed entries ahead of it)
+    exact = st.tier != _APPROX
+    ce_d2, ce_ids = sort_rows(jnp.where(exact, st.d2, jnp.inf),
+                              jnp.where(exact, st.ids, INVALID_ID))
     k = params.k
     res = SearchResult(
-        ids=st.ce_ids[:, :k],
-        dists=jnp.sqrt(jnp.maximum(st.ce_d2[:, :k], 0.0)),
+        ids=ce_ids[:, :k],
+        dists=jnp.sqrt(jnp.maximum(ce_d2[:, :k], 0.0)),
         n_dist_comps=st.n_dist,
         n_approx_comps=st.n_approx,
         n_hops=st.n_hops,
@@ -262,9 +279,11 @@ def probing_search(
         saturated=st.saturated,
         n_encounters=st.n_enc,
         n_iters=st.n_iters,
+        # every exact evaluation but the start's is a probe
+        n_probes=st.n_dist - 1,
     )
     if with_candidates:
-        return res, st.ce_ids, jnp.sqrt(jnp.maximum(st.ce_d2, 0.0))
+        return res, ce_ids, jnp.sqrt(jnp.maximum(ce_d2, 0.0))
     return res
 
 

@@ -144,7 +144,9 @@ class SearchResult:
     before the α-stop rule fired (bound may not hold for those).
     ``n_iters`` counts the lock-step loop iterations each row was active in;
     its largest value over the batch's rows is the loop's trip count.  A
-    result made without the loop leaves it ``None``.
+    result made without the loop leaves it ``None``.  ``n_probes`` counts
+    the candidates the probing engine promoted to its exact tier; the other
+    engines leave it ``None``.
     """
 
     ids: jax.Array
@@ -156,6 +158,7 @@ class SearchResult:
     saturated: jax.Array
     n_encounters: jax.Array = None
     n_iters: jax.Array = None
+    n_probes: jax.Array = None
 
 
 @_register

@@ -23,6 +23,8 @@ serve_breaker_transitions_total
 serve_rung                              gauge      current ladder rung
 search_dist_comps_total                 counter    exact distance evals (Exp-5)
 search_approx_comps_total               counter    quantized evals (δ-EMQG)
+search_probes_total                     counter    ids promoted to the exact
+                                                   tier (probing engine only)
 search_hops_total                       counter    expansions
 search_encounters_total                 counter    pre-dedup candidate encounters
 search_saturated_total                  counter    queries whose adaptive l capped
@@ -180,6 +182,9 @@ def record_search_result(registry: MetricsRegistry, res,
     if getattr(res, "n_approx_comps", None) is not None:
         registry.counter("search_approx_comps_total").inc(
             float(rows(res.n_approx_comps).sum()))
+    if getattr(res, "n_probes", None) is not None:
+        registry.counter("search_probes_total").inc(
+            float(rows(res.n_probes).sum()))
     if getattr(res, "n_encounters", None) is not None:
         registry.counter("search_encounters_total").inc(
             float(rows(res.n_encounters).sum()))

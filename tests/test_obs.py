@@ -414,6 +414,61 @@ def test_record_search_result_lockstep_counters():
     assert m.counter("search_slot_iters_total").value == 7 * 4
 
 
+def _has_family(registry, name):
+    return any(fam == name for fam, *_ in registry.families())
+
+
+def test_probes_counted_for_probing_engine_only(tiny):
+    """``search_probes_total`` is the probing engine's promotions to its
+    exact tier over the live rows (every exact distance but the start's);
+    the exact engine records none, so the family is absent."""
+    from repro.core import probing_search
+    from repro.core.emqg import from_graph
+
+    index = from_graph(tiny["graph"])
+    m = MetricsRegistry()
+    srv = AnnServer(index, PARAMS, max_batch=32, buckets=(32,), metrics=m)
+    srv.submit_many(tiny["queries"][:20])
+    srv.drain()
+    res = probing_search(index, jnp.asarray(tiny["queries"][:20]), PARAMS)
+    n_probes = np.asarray(res.n_probes)
+    np.testing.assert_array_equal(n_probes,
+                                  np.asarray(res.n_dist_comps) - 1)
+    assert (n_probes > 0).all()
+    assert m.counter("search_probes_total").value == n_probes.sum()
+    assert 0 < n_probes.sum() < m.counter("search_hops_total").value
+
+    m2 = MetricsRegistry()
+    srv = AnnServer(tiny["graph"], PARAMS, max_batch=32, buckets=(32,),
+                    metrics=m2)
+    srv.submit_many(tiny["queries"][:20])
+    srv.drain()
+    assert _has_family(m2, "search_hops_total")
+    assert not _has_family(m2, "search_probes_total")
+
+
+def test_emqg_build_events_align_and_quantize():
+    """The ``align_degree`` event counts the short rows and the edges it
+    added; ``build_emqg`` times the RaBitQ fit as a ``quantize`` event."""
+    from repro.core import BuildParams, build_emqg
+
+    rng = np.random.default_rng(5)
+    base = rng.normal(size=(256, 8)).astype(np.float32)
+    m = MetricsRegistry()
+    idx = build_emqg(base, BuildParams(max_degree=12, beam_width=16, t=12,
+                                       iters=1, delta=0.2, block=128),
+                     metrics=m)
+    ev = {e["phase"]: e for e in m.events if e["name"] == "build_progress"}
+    align = ev["align_degree"]
+    deg = np.asarray(idx.graph.degrees())
+    assert 0 < align["deficient"] <= 256
+    assert align["padded"] > 0
+    assert (deg == 12).all()
+    q = ev["quantize"]
+    assert q["nodes"] == 256 and q["elapsed_s"] > 0
+    assert list(ev)[-1] == "quantize"
+
+
 def test_pad_rows_not_double_billed(tiny):
     """A 5-request batch padded to bucket 32 must aggregate device counters
     over 5 rows, not 32."""

@@ -107,3 +107,77 @@ def test_invalid_ids_inf():
     est = rabitq.estimate_sqdist(codes, ctx,
                                  jnp.asarray([0, -1, 3], jnp.int32))
     assert bool(jnp.isinf(est[1])) and bool(jnp.isfinite(est[0]))
+
+
+# ---------------------------------------------------------------------------
+# Against the float64 reference (``repro.testing.rabitq_ref``), written from
+# the estimator's formula.
+# ---------------------------------------------------------------------------
+
+def _np_unpack(words, d):
+    """bit j of word w = dimension 32·w + j (numpy, not the program's)."""
+    w = np.asarray(words, np.uint64)
+    bits = (w[:, :, None] >> np.arange(32, dtype=np.uint64)) & 1
+    return bits.reshape(w.shape[0], -1)[:, :d].astype(bool)
+
+
+@pytest.mark.parametrize("shape", ["sift_like", "gaussian"])
+def test_fit_and_estimate_match_float64_reference(shape):
+    from repro.testing import rabitq_ref
+
+    rng = np.random.default_rng(11)
+    if shape == "sift_like":      # integers in [0, 255], a third zeros
+        base = np.clip(np.rint(rng.normal(20, 30, (600, 128))), 0, 255)
+        queries = np.clip(np.rint(rng.normal(20, 30, (8, 128))), 0, 255)
+    else:
+        base = rng.normal(size=(600, 24))
+        queries = rng.normal(size=(8, 24))
+    base, queries = base.astype(np.float32), queries.astype(np.float32)
+    codes = rabitq.fit(jnp.asarray(base), jax.random.PRNGKey(3))
+    ref = rabitq_ref.fit(base, np.asarray(codes.rotation))
+    d = base.shape[1]
+
+    # scalars: f32 sums of d terms against f64 read at most 3.2e-7 apart
+    # here; rtol 2e-6 (about 16 f32 ulps) leaves room for another
+    # summation order and no more
+    np.testing.assert_allclose(np.asarray(codes.norms), ref.norms, rtol=2e-6)
+    np.testing.assert_allclose(np.asarray(codes.ip_xo), ref.ip_xo, rtol=2e-6)
+    np.testing.assert_allclose(np.asarray(codes.center), ref.center,
+                               rtol=1e-5, atol=1e-5 * np.abs(ref.center).max())
+
+    # codes: equal bit for bit, except where the residual's f32 rounding
+    # (at most 1e-5 of the row's norm) can flip a coordinate's sign
+    bits = _np_unpack(np.asarray(codes.codes), d)
+    ambiguous = np.abs(ref.r) <= 1e-5 * ref.norms[:, None]
+    assert (bits == ref.bits)[~ambiguous].all()
+    exact_rows = ~ambiguous.any(axis=1)
+    assert exact_rows.mean() > 0.95
+
+    # estimates, on rows whose codes agree: the estimate's three terms are
+    # each within f32 rounding of (‖r‖ + ‖r_q‖)² (read: at most 2.1e-7 of
+    # it), so 2e-6 of that
+    ids = np.flatnonzero(exact_rows).astype(np.int32)
+    for q in queries:
+        ctx = rabitq.prepare_query(codes, jnp.asarray(q))
+        got = np.asarray(rabitq.estimate_sqdist(codes, ctx, jnp.asarray(ids)))
+        want = rabitq_ref.estimate_sqdist(ref, q, ids)
+        r_q = (q.astype(np.float64) - ref.center) @ ref.rotation.T
+        scale = (ref.norms[ids] + np.linalg.norm(r_q)) ** 2
+        assert (np.abs(got - want) <= 2e-6 * scale).all()
+        # INVALID ids estimate +inf
+        bad = rabitq.estimate_sqdist(codes, ctx, jnp.asarray([-1], jnp.int32))
+        assert np.isinf(np.asarray(bad)).all()
+
+
+def test_float64_reference_is_the_estimator():
+    """The reference itself, on a case worked by hand: a vector whose
+    residual lies on a sign pattern is estimated exactly (⟨x̄, o⟩ = 1)."""
+    from repro.testing import rabitq_ref
+
+    d = 4
+    v = np.array([[1.0, 1.0, -1.0, -1.0], [-1.0, -1.0, 1.0, 1.0]])
+    ref = rabitq_ref.fit(v, np.eye(d))
+    np.testing.assert_allclose(ref.ip_xo, [1.0, 1.0])
+    q = np.array([2.0, 0.0, 0.0, 1.0])
+    est = rabitq_ref.estimate_sqdist(ref, q, [0, 1])
+    np.testing.assert_allclose(est, np.sum((v - q) ** 2, axis=1))
