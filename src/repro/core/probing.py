@@ -7,7 +7,8 @@ exact frontier has stopped improving.  The adaptive outer-``l`` loop and the
 α stop rule are inherited from Algorithm 3 and apply to the exact tier.
 
 ``probing_search`` is the batch-level beam engine — the only Algorithm-5
-engine in the repo.  One ``while_loop`` drives the whole batch; per iteration
+engine in the repo.  One lock-step loop drives the whole batch (compacted
+as rows finish, ``lockstep.run``); per iteration
 each query either *probes* its ``beam_width`` best unprobed approximate
 candidates (their exact distances are evaluated in one fused gather+L2 call
 over ``[B, W]`` ids) or *expands* its W best unvisited exact candidates
@@ -43,7 +44,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from . import rabitq
+from . import lockstep, rabitq
 from .bitset import bitset_make, bitset_set, bitset_test, unique_per_row
 from .search import (
     _beam_search_batch,
@@ -79,6 +80,7 @@ class _BeamPState(NamedTuple):
     n_enc: jax.Array       # int32[B]  candidate encounters (pre-dedup)
     n_hops: jax.Array      # int32[B]
     n_iters: jax.Array     # int32[B]  loop iterations the row was active in
+    n_resident: jax.Array  # int32[B]  loop iterations the row held a slot in
     done: jax.Array        # bool[B]
     saturated: jax.Array   # bool[B]
 
@@ -87,18 +89,21 @@ def _beam_probing_batch(
     neighbors: jax.Array,      # int32[n, M]
     n_nodes: int,
     batch_exact: Callable,     # (queries [B,d], ids [B,K]) → d2 [B,K]
-    batch_approx: Callable,    # (ids [B,K]) → d2 [B,K]
+    batch_approx: Callable,    # (ctx [B], ids [B,K]) → d2 [B,K]
     queries: jax.Array,
+    ctx,                       # rabitq.QueryCtx of each row
     start: jax.Array,
     p: SearchParams,
 ) -> _BeamPState:
+    """Algorithm 5's hop loop, driven by ``lockstep.run``: the batch
+    compacts as its rows finish, the queries and their RaBitQ contexts
+    travelling with the rows."""
     B = queries.shape[0]
     C = p.l_max + 1
     W = resolve_beam_width(p, C)
     M = neighbors.shape[1]
 
     pos = jnp.arange(C, dtype=jnp.int32)[None, :]
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
 
     d2_s = batch_exact(queries, start[:, None])[:, 0]
     st = _BeamPState(
@@ -113,6 +118,7 @@ def _beam_probing_batch(
         n_enc=jnp.ones((B,), jnp.int32),
         n_hops=jnp.zeros((B,), jnp.int32),
         n_iters=jnp.zeros((B,), jnp.int32),
+        n_resident=jnp.zeros((B,), jnp.int32),
         done=jnp.zeros((B,), jnp.bool_),
         saturated=jnp.zeros((B,), jnp.bool_),
     )
@@ -122,13 +128,13 @@ def _beam_probing_batch(
 
     # The phases run under the exact engine's ``hop.*`` named scopes
     # (``search._beam_search_batch``), with the RaBitQ estimates under
-    # ``hop.estimate``; a scope only tags the ops' metadata.
-    def cond(s: _BeamPState):
-        with jax.named_scope("hop.transition"):
-            return jnp.any(active_mask(s))
-
-    def body(s: _BeamPState) -> _BeamPState:
+    # ``hop.estimate``; a scope only tags the ops' metadata.  The body runs
+    # at each stage's batch size S.
+    def body(s: _BeamPState, inputs) -> _BeamPState:
+        queries, ctx = inputs
+        S = s.l.shape[0]
         with jax.named_scope("hop.select"):
+            rows = jnp.arange(S, dtype=jnp.int32)[:, None]
             # The window is the first l entries of one list that ranks
             # exact candidates by their distance and unprobed ones by their
             # estimate; a probed entry leaves its estimate's rank and
@@ -189,7 +195,7 @@ def _beam_probing_batch(
         with jax.named_scope("hop.expand"):
             nbrs = jnp.take(neighbors, jnp.maximum(u_ids, 0), axis=0)
             nbrs = jnp.where(selv_u[:, :, None], nbrs,
-                             INVALID_ID).reshape(B, W * M)
+                             INVALID_ID).reshape(S, W * M)
             # encounters: valid neighbor ids pre-dedup, plus probed ones
             n_enc = s.n_enc + jnp.sum(nbrs >= 0, axis=1).astype(jnp.int32) \
                 + jnp.sum(w_ids >= 0, axis=1).astype(jnp.int32)
@@ -200,7 +206,7 @@ def _beam_probing_batch(
             seen = bitset_set(s.seen, new_ids)
 
         with jax.named_scope("hop.estimate"):                # expand: approx
-            d2a = batch_approx(new_ids)                        # [B, W·M]
+            d2a = batch_approx(ctx, new_ids)                   # [B, W·M]
             n_approx = s.n_approx \
                 + jnp.sum(new_ids >= 0, axis=1).astype(jnp.int32)
 
@@ -222,12 +228,12 @@ def _beam_probing_batch(
                 p, d2, s.l, s.done, s.saturated, conv)
             n_iters = s.n_iters + active.astype(jnp.int32)
 
-        return _BeamPState(
+        return s._replace(
             ids=ids, d2=d2, tier=tier, seen=seen, d2_last=d2_last, l=l,
             n_dist=n_dist, n_approx=n_approx, n_enc=n_enc, n_hops=n_hops,
             n_iters=n_iters, done=done, saturated=saturated)
 
-    return jax.lax.while_loop(cond, body, st)
+    return lockstep.run(active_mask, body, st, (queries, ctx))
 
 
 @partial(jax.jit, static_argnames=("params", "use_kernel", "with_candidates",
@@ -256,13 +262,13 @@ def probing_search(
 
     ctx = jax.vmap(lambda q: rabitq.prepare_query(codes, q))(queries)
 
-    def batch_approx(ids):
+    def batch_approx(ctx, ids):
         return jax.vmap(
             lambda c, i: rabitq.estimate_sqdist(codes, c, i, bitdot_fn=bitdot_fn)
         )(ctx, ids)
 
     st = _beam_probing_batch(g.neighbors, g.n, batch_exact, batch_approx,
-                             queries, start, params)
+                             queries, ctx, start, params)
     # the exact tier, in order: an answer never carries an estimate (a row
     # cut off by ``max_hops`` may hold unprobed entries ahead of it)
     exact = st.tier != _APPROX
@@ -279,6 +285,7 @@ def probing_search(
         saturated=st.saturated,
         n_encounters=st.n_enc,
         n_iters=st.n_iters,
+        n_resident=st.n_resident,
         # every exact evaluation but the start's is a probe
         n_probes=st.n_dist - 1,
     )
@@ -311,7 +318,7 @@ def ags_search(index: EMQGIndex, queries: jax.Array, params: SearchParams,
     The generic ``_beam_search_batch`` traversal only consumes the graph
     topology and a ``batch_dist`` callable, so swapping in the RaBitQ
     estimator yields the approximate-guided frontier for free — the whole
-    batch walks in one ``while_loop`` with the same bitset dedup and
+    batch walks in one lock-step loop with the same bitset dedup and
     masked adaptive transitions as the exact engine.  The final candidate
     buffers (up to ``l_max+1`` ids per query) are then reranked with one
     fused exact gather+L2 call (``backend`` selects its implementation).
@@ -326,11 +333,12 @@ def ags_search(index: EMQGIndex, queries: jax.Array, params: SearchParams,
 
     ctx = jax.vmap(lambda q: rabitq.prepare_query(codes, q))(queries)
 
-    def batch_approx(qs, ids):
+    def batch_approx(ctx, ids):
         return jax.vmap(
             lambda c, i: rabitq.estimate_sqdist(codes, c, i))(ctx, ids)
 
-    st = _beam_search_batch(g, queries, start, params, batch_approx)
+    # the traversal's rows carry their RaBitQ contexts, not the queries
+    st = _beam_search_batch(g, ctx, start, params, batch_approx)
 
     # exact rerank of the whole final buffer, one fused call
     batch_exact = make_batch_dist_fn(g.vectors, backend)
@@ -349,4 +357,5 @@ def ags_search(index: EMQGIndex, queries: jax.Array, params: SearchParams,
         saturated=st.saturated,
         n_encounters=st.n_enc,
         n_iters=st.n_iters,
+        n_resident=st.n_resident,
     )

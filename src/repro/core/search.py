@@ -5,7 +5,7 @@ adaptive top-k search) of the paper as a *single* parameterized engine,
 reformulated for lock-step execution on TPU.
 
 ``search`` is the **batch-level beam engine** — the only graph-search engine
-in the repo.  One ``while_loop`` drives the whole query batch: each
+in the repo.  One lock-step loop drives the whole query batch: each
 iteration selects the ``beam_width`` (W) best unvisited in-window candidates
 per query, gathers all ``B×W×M`` neighbor ids at once, dedups them against a
 packed ``uint32`` visited bitset (O(1) test/set/clear — see ``bitset.py``),
@@ -14,7 +14,9 @@ and evaluates every fresh distance in a *single* fused gather+L2 call over
 — one big contraction per hop for the MXU instead of B tiny ones; on CPU it
 lowers to the identical-math jnp path.  Queries that have exhausted their
 window take the adaptive-α transition (grow ``l`` or stop) in the same
-lock-step iteration; finished queries are masked no-ops.
+lock-step iteration; finished queries are masked no-ops, and the loop
+compacts the batch to a smaller static shape as they finish
+(``lockstep.run``), so the tail runs on the rows still active.
 
 Semantics:
 
@@ -59,6 +61,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from . import lockstep
 from .bitset import (
     bitset_clear,
     bitset_make,
@@ -159,6 +162,7 @@ class _BeamState(NamedTuple):
     n_enc: jax.Array       # int32[B]    candidate encounters (pre-dedup)
     n_hops: jax.Array      # int32[B]    expansions
     n_iters: jax.Array     # int32[B]    loop iterations the row was active in
+    n_resident: jax.Array  # int32[B]    loop iterations the row held a slot in
     done: jax.Array        # bool[B]
     saturated: jax.Array   # bool[B]     l hit l_max before the α-rule fired
 
@@ -242,22 +246,25 @@ def faithful_prune_merge(cand_ids, cand_d2, cand_vis, new_ids, d2_new,
 
 def _beam_search_batch(
     graph: GraphIndex,
-    queries: jax.Array,        # f32[B, d]
+    inputs,                    # per-row inputs of batch_dist: f32[B, d] queries
     start: jax.Array,          # int32[B]
     p: SearchParams,
-    batch_dist: Callable,
+    batch_dist: Callable,      # (inputs, ids [B, K]) → d2 [B, K]
     faithful_prune: bool = False,
 ) -> _BeamState:
-    B = queries.shape[0]
+    """The beam engine's hop loop (``search``, and AGS's traversal), driven
+    by ``lockstep.run``: the batch compacts as its rows finish.  ``inputs``
+    (the queries, or any pytree of per-row arrays such as RaBitQ query
+    contexts) travel with the rows and reach ``batch_dist`` with them."""
+    B = start.shape[0]
     C = p.l_max + 1
     W = resolve_beam_width(p, C)
     M = graph.neighbors.shape[1]
     n = graph.n
 
     pos = jnp.arange(C, dtype=jnp.int32)[None, :]      # [1, C]
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]     # [B, 1]
 
-    d2_start = batch_dist(queries, start[:, None])[:, 0]
+    d2_start = batch_dist(inputs, start[:, None])[:, 0]
     st = _BeamState(
         cand_ids=jnp.full((B, C), INVALID_ID, jnp.int32).at[:, 0].set(start),
         cand_d2=jnp.full((B, C), jnp.inf, jnp.float32).at[:, 0].set(d2_start),
@@ -268,6 +275,7 @@ def _beam_search_batch(
         n_enc=jnp.ones((B,), jnp.int32),
         n_hops=jnp.zeros((B,), jnp.int32),
         n_iters=jnp.zeros((B,), jnp.int32),
+        n_resident=jnp.zeros((B,), jnp.int32),
         done=jnp.zeros((B,), jnp.bool_),
         saturated=jnp.zeros((B,), jnp.bool_),
     )
@@ -278,13 +286,12 @@ def _beam_search_batch(
     # Each phase of an iteration runs under a ``hop.*`` named scope, which
     # only tags the ops' metadata: a profile of the compiled loop can then
     # charge every device op to its phase, whatever XLA names the fusion.
-    def cond(s: _BeamState):
-        with jax.named_scope("hop.transition"):
-            return jnp.any(active_mask(s))
-
-    def body(s: _BeamState) -> _BeamState:
+    # The body runs at each stage's batch size S.
+    def body(s: _BeamState, inputs) -> _BeamState:
+        S = s.l.shape[0]
         # -- frontier selection: W best unvisited in-window per query --------
         with jax.named_scope("hop.select"):
+            rows = jnp.arange(S, dtype=jnp.int32)[:, None]
             active = active_mask(s)
             window = (pos < s.l[:, None]) & (s.cand_ids >= 0) & (~s.cand_vis)
             window &= active[:, None]
@@ -301,7 +308,7 @@ def _beam_search_batch(
         with jax.named_scope("hop.expand"):
             nbrs = jnp.take(graph.neighbors, jnp.maximum(u_ids, 0), axis=0)
             nbrs = jnp.where(selv[:, :, None], nbrs,
-                             INVALID_ID).reshape(B, W * M)
+                             INVALID_ID).reshape(S, W * M)
             # encounters: every valid neighbor id this hop produced,
             # pre-dedup — the dedup-independent Exp-5 counter (the bitset
             # never re-evaluates pruned-then-reencountered nodes, so n_dist
@@ -316,7 +323,7 @@ def _beam_search_batch(
 
         # -- the hot path: one fused gather+L2 over the whole batch ----------
         with jax.named_scope("hop.distance"):
-            d2_new = batch_dist(queries, new_ids)
+            d2_new = batch_dist(inputs, new_ids)
             n_dist = s.n_dist + jnp.sum(new_ids >= 0, axis=1).astype(jnp.int32)
 
         with jax.named_scope("hop.merge"):
@@ -338,12 +345,12 @@ def _beam_search_batch(
             # largest is the loop's trip count
             n_iters = s.n_iters + active.astype(jnp.int32)
 
-        return _BeamState(cand_ids=cand_ids, cand_d2=cand_d2,
+        return s._replace(cand_ids=cand_ids, cand_d2=cand_d2,
                           cand_vis=cand_vis, seen=seen, l=l, n_dist=n_dist,
                           n_enc=n_enc, n_hops=n_hops, n_iters=n_iters,
                           done=done, saturated=saturated)
 
-    return jax.lax.while_loop(cond, body, st)
+    return lockstep.run(active_mask, body, st, inputs)
 
 
 @partial(jax.jit, static_argnames=("params", "faithful_prune",
@@ -388,6 +395,7 @@ def search(
         saturated=st.saturated,
         n_encounters=st.n_enc,
         n_iters=st.n_iters,
+        n_resident=st.n_resident,
     )
     if with_candidates:
         return res, st.cand_ids, jnp.sqrt(jnp.maximum(st.cand_d2, 0.0))

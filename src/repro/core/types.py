@@ -144,7 +144,11 @@ class SearchResult:
     before the α-stop rule fired (bound may not hold for those).
     ``n_iters`` counts the lock-step loop iterations each row was active in;
     its largest value over the batch's rows is the loop's trip count.  A
-    result made without the loop leaves it ``None``.  ``n_probes`` counts
+    result made without the loop leaves it ``None``.  ``n_resident`` counts
+    the iterations in which the row held a slot in the loop's batch, active
+    or finished: the loop compacts its batch as rows finish, so its sum over
+    the rows is the row iterations the loop ran (``None`` without the
+    loop, as ``n_iters``).  ``n_probes`` counts
     the candidates the probing engine promoted to its exact tier; the other
     engines leave it ``None``.
     """
@@ -159,6 +163,7 @@ class SearchResult:
     n_encounters: jax.Array = None
     n_iters: jax.Array = None
     n_probes: jax.Array = None
+    n_resident: jax.Array = None
 
 
 @_register

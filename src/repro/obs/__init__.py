@@ -32,6 +32,8 @@ search_row_iters_total                  counter    lock-step iterations live row
                                                    were active in (Σ n_iters)
 search_slot_iters_total                 counter    lock-step iterations × bucket
                                                    rows (the loop's slots)
+search_resident_iters_total             counter    slots the compacting loop ran:
+                                                   Σ n_resident, pads included
 search_final_l                          histogram  per-query final beam length
 shard_live{shard}                       gauge      1 = some replica live
 shard_coverage                          gauge      live logical shards / S
@@ -68,7 +70,9 @@ Device phases: the beam engines' loop bodies run each phase under a
 ``jax.named_scope`` — ``hop.select``, ``hop.expand``, ``hop.visited``,
 ``hop.distance``, ``hop.merge``, ``hop.transition``, and in the probing
 engine ``hop.estimate`` (the RaBitQ estimates) — so the compiled ops'
-metadata names the phase each one belongs to.
+metadata names the phase each one belongs to.  Between the stages of the
+compacting loop (``core/lockstep.py``), the gather of the active rows and
+the write-back of a stage's rows run under ``hop.compact``.
 
 Everything here is stdlib-only (the mirror imports ``jax`` at the first
 span, and is off where it cannot) and observation-only: enabling metrics
@@ -128,6 +132,8 @@ def declare_serve_metrics(registry: MetricsRegistry,
                      help="lock-step iterations live rows were active in")
     registry.counter("search_slot_iters_total",
                      help="lock-step iterations times bucket rows")
+    registry.counter("search_resident_iters_total",
+                     help="row slots the compacting lock-step loop ran")
     registry.histogram("search_final_l", buckets=DEFAULT_WORK_BUCKETS,
                        help="per-query final beam length")
     registry.gauge("shard_coverage",
@@ -168,7 +174,9 @@ def record_search_result(registry: MetricsRegistry, res,
 
     ``n_iters``, where the result has it, gives the lock-step counters: the
     live rows' iterations, and the loop's trip count (the largest over all
-    rows, pads included) times the rows the loop ran on.
+    rows, pads included) times the rows the loop ran on.  ``n_resident``
+    gives the slots the loop, compacted as rows finish, actually ran: its
+    sum over all rows, pads included.
     """
     import numpy as np  # deferred: keep `repro.obs` importable stdlib-only
 
@@ -194,6 +202,9 @@ def record_search_result(registry: MetricsRegistry, res,
             float(rows(it).sum()))
         registry.counter("search_slot_iters_total").inc(
             float(it.max(initial=0)) * it.size)
+    if getattr(res, "n_resident", None) is not None:
+        registry.counter("search_resident_iters_total").inc(
+            float(np.asarray(res.n_resident).sum()))
     registry.counter("search_saturated_total").inc(
         float(rows(res.saturated).sum()))
     registry.histogram("search_final_l", buckets=DEFAULT_WORK_BUCKETS) \

@@ -414,6 +414,24 @@ def test_record_search_result_lockstep_counters():
     assert m.counter("search_slot_iters_total").value == 7 * 4
 
 
+def test_resident_iters_counter_sums_n_resident(tiny):
+    """``search_resident_iters_total`` is declared with the serve schema and
+    adds a batch's ``n_resident`` over every row, pads included: the slots
+    the compacting loop ran, no more than the lock-step slots."""
+    m = declare_serve_metrics(MetricsRegistry())
+    assert _has_family(m, "search_resident_iters_total")
+    srv = AnnServer(tiny["graph"], PARAMS, max_batch=48, buckets=(48,),
+                    metrics=m)
+    srv.submit_many(tiny["queries"][:40])     # 40 live rows, 8 pad
+    srv.drain()
+    qs = np.concatenate([tiny["queries"][:40],
+                         np.repeat(tiny["queries"][39:40], 8, axis=0)])
+    r = jax.device_get(srv._search(jnp.asarray(qs)))
+    resident = m.counter("search_resident_iters_total").value
+    assert resident == int(r.n_resident.sum()) > 0
+    assert resident <= m.counter("search_slot_iters_total").value
+
+
 def _has_family(registry, name):
     return any(fam == name for fam, *_ in registry.families())
 

@@ -195,17 +195,19 @@ def test_probing_program_phases_scoped(probing_hlo):
 
 
 def test_search_kernel_calls_read_as_all_rows(one_chip, monkeypatch):
-    """The bulk batch's program (``search`` at B=1,024): every
-    ``gather_l2_tiled`` call in it, found by the benchmark's custom-call
-    reader, has three operands (ids, the corpus, the queries) and is
-    charged B·K′ gathered rows of d floats — the start call's [B, 1] ids
-    padded to 8 slots, the loop's [B, 64] — so the kernel's roofline reads
-    the same work whatever the kernel's blocking."""
+    """The bulk batch's program (``search`` at B=1,024), with the kernel and
+    every ``hop.*`` scope in its loops: every ``gather_l2_tiled`` call in
+    it, found by the benchmark's custom-call reader, has three operands
+    (ids, the corpus, the queries) and is charged its own rows, B_s·K′
+    gathered rows of d floats — the start call's [B, 1] ids padded to 8
+    slots, and the [B_s, 64] of each stage of the compacting loop, B_s
+    running down the ladder from B — so the kernel's roofline reads the
+    same work whatever the kernel's blocking."""
     import re
     import sys
     from pathlib import Path
 
-    from repro.core import search
+    from repro.core import lockstep, search
 
     sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
     from harness import kernels
@@ -220,14 +222,20 @@ def test_search_kernel_calls_read_as_all_rows(one_chip, monkeypatch):
                           max_hops=4096)
     hlo = search.lower(graph, _sds((B, D), jnp.float32, one_chip), params,
                        backend="kernel_tiled").compile().as_text()
+    assert "tpu_custom_call" in hlo
+    scopes, kernel = _loop_scopes(hlo)
+    assert scopes == HOP_SCOPES
+    assert kernel == {"hop.distance"}
     calls = kernels.custom_calls(hlo, "gather_l2_tiled")
     named = re.findall(r"^\s*%?(gather_l2_tiled[\w.\-]*) = \S+ custom-call\(",
                        hlo, re.M)
     assert calls and sorted(calls) == sorted(named)
+    stages = lockstep.ladder(B)
+    assert len(stages) > 1
     rows = set()
     for operands in calls.values():
         (ids_t, _), (base_t, base), (q_t, q) = operands
         assert (ids_t, base_t, q_t) == ("s32", "f32", "f32")
-        assert base == (N, D) and q[0] == B
+        assert base == (N, D) and q[0] in stages
         rows.add(kernels.gather_l2_tiled_work(operands)["flops"] // (3 * D))
-    assert rows == {B * 8, B * M}
+    assert rows == {B * 8} | {b * M for b in stages}
