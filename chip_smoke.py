@@ -114,21 +114,20 @@ def configure(args) -> dict:
     """Params from configs/sift1m.py at full width; tiny for --rehearse."""
     import dataclasses
 
-    from repro.configs.sift1m import ARCH
+    from repro.configs import sift1m
 
-    mc = ARCH.model_cfg
     size = REHEARSE if args.rehearse else FULL
-    bp = dataclasses.replace(mc["build"], delta=DELTA, block=size["block"],
+    bp = dataclasses.replace(sift1m.BUILD, delta=DELTA, block=size["block"],
                              beam_width=size["beam_width"],
                              iters=size["iters"], seed=args.seed)
-    sp = mc["search"]
+    sp = sift1m.SEARCH
     if args.rehearse:
         bp = dataclasses.replace(bp, max_degree=size["max_degree"],
                                  t=size["t"])
         sp = dataclasses.replace(sp, l_max=size["l_max"],
                                  max_hops=size["max_hops"])
     n = size["n_four"] if args.four_chips else size["n"]
-    say(f"config sift1m: n={n} (config {mc['n']}) d={mc['dim']} "
+    say(f"config sift1m: n={n} (config {sift1m.N}) d={sift1m.DIM} "
         f"M={bp.max_degree} L={bp.beam_width} t={bp.t} iters={bp.iters} "
         f"k={sp.k} l_max={sp.l_max} alpha={sp.alpha} "
         f"W={sp.beam_width} batch={size['batch']}")
@@ -137,17 +136,17 @@ def configure(args) -> dict:
             "below is a control-flow check, not a device measurement")
     else:
         key = "n_four" if args.four_chips else "n"
-        say(f"cut: n {mc['n']} -> {n}: {CUT_REASONS[key]}")
+        say(f"cut: n {sift1m.N} -> {n}: {CUT_REASONS[key]}")
         say(f"cut: build L (BuildParams.beam_width) "
-            f"{mc['build'].beam_width} -> {bp.beam_width}: "
+            f"{sift1m.BUILD.beam_width} -> {bp.beam_width}: "
             f"{CUT_REASONS['beam_width']}")
         say(f"cut: build refinement iterations (BuildParams.iters) "
-            f"{mc['build'].iters} -> {bp.iters}: {CUT_REASONS['iters']}")
+            f"{sift1m.BUILD.iters} -> {bp.iters}: {CUT_REASONS['iters']}")
     say(f"change: fixed delta={DELTA} instead of the adaptive delta_t rule, "
         f"so the (1/delta) bound is finite ({1 / DELTA:g})")
     say(f"build block={bp.block} nodes per device batch (config default "
-        f"{mc['build'].block})")
-    return dict(size=size, bp=bp, sp=sp, n=n, d=mc["dim"])
+        f"{sift1m.BUILD.block})")
+    return dict(size=size, bp=bp, sp=sp, n=n, d=sift1m.DIM)
 
 
 def make_data(n: int, d: int, n_queries: int, seed: int):

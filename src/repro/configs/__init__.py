@@ -1,4 +1,2 @@
-"""Per-architecture configs (one module per assigned arch + the paper's own
-SIFT1M serving config).  ``get_arch`` / ``all_archs`` are the public API."""
-
-from .base import ArchSpec, ShapeSpec, all_archs, get_arch, load_all  # noqa: F401
+"""Deployment configs: ``sift1m``, the paper's own SIFT1M serving config, as
+plain module constants."""

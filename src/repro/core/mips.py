@@ -1,6 +1,6 @@
 """MIPS → L2 reduction for inner-product retrieval over a δ-EMG.
 
-The recsys retrieval head maximizes ⟨u, v⟩ while the δ-EMG index answers
+Inner-product retrieval maximizes ⟨u, v⟩ while the δ-EMG index answers
 min-L2 queries.  The standard exact reduction (Bachrach et al. 2014)
 augments items with one extra coordinate:
 

@@ -1,10 +1,1 @@
-from .synthetic import (  # noqa: F401
-    clustered_vectors,
-    lm_batch,
-    make_markov_lm,
-    recsys_ctr_batch,
-    recsys_seq_batch,
-    sbm_graph,
-    molecule_batch,
-)
-from .sampler import CSRGraph, fanout_sample  # noqa: F401
+from .synthetic import clustered_vectors  # noqa: F401

@@ -7,8 +7,6 @@ Hence two kernel families:
     l2dist/   — batched/fused-gather squared-L2 (exact tier)
     bitdot/   — packed 1-bit RaBitQ code contraction + fused estimator
                 (approximate tier; TPU-native FastScan replacement)
-    flashattn/ — flash attention fwd with VMEM-resident online-softmax
-                 state (the §Perf-identified lever for the LM memory term)
 
 Each provides  <name>.py (pl.pallas_call + BlockSpec),  ops.py (jitted
 wrapper w/ CPU interpret fallback),  ref.py (pure-jnp oracle).
@@ -16,4 +14,3 @@ wrapper w/ CPU interpret fallback),  ref.py (pure-jnp oracle).
 
 from .l2dist.ops import batched_l2, gather_l2  # noqa: F401
 from .bitdot.ops import bitdot, fused_estimate  # noqa: F401
-from .flashattn.ops import flash_attention as flash_attention_kernel  # noqa: F401

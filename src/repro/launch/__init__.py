@@ -1,2 +1,2 @@
-"""Launch layer: production mesh, sharding policies, per-cell step builders,
-dry-run driver, and train/serve entry points."""
+"""Launch layer: the serving CLI (``serve``) and the compile-cache setup
+(``cache``) that the entry points share."""

@@ -1,8 +1,9 @@
 """Where JAX keeps its persistent compilation cache.
 
-Entry points (``chip_smoke.py``, ``repro.launch.serve``, ``benchmarks/run.py``)
-call :func:`use_compile_cache` once at start-up; importing ``repro`` never
-does.  A cache hits only at the path that wrote it, so the path is fixed:
+Entry points (``chip_smoke.py``, ``repro.launch.serve``, ``bench/run.py``,
+``bench/knee.py``) call :func:`use_compile_cache` once at start-up;
+importing ``repro`` never does.  A cache hits only at the path that wrote
+it, so the path is fixed:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself, and no other
   directory is set in code;

@@ -13,9 +13,7 @@ suite):
   ``time.time()`` latency math survives in ``repro/serve``.
 * **Fixed-bucket histograms.**  Latency histograms use a fixed exponential
   bucket ladder so p50/p95/p99 extraction is O(#buckets), mergeable across
-  processes, and *identical math* between the benchmark harness and the
-  serve-time exporters (``benchmarks/qps_recall.py`` observes into the same
-  ``Histogram``).
+  processes, and *identical math* for every reader of a snapshot.
 * **Labels are first-class but flat.**  A metric family (one name) has
   children keyed by a sorted ``(key, value)`` label tuple — enough for
   ``{shard="3"}`` / ``{status="ok"}`` cardinality, no label matchers.

@@ -1,5 +1,4 @@
 from .ann_server import AnnServer, ServeStats  # noqa: F401
-from .lm_server import generate  # noqa: F401
 from .resilience import (  # noqa: F401
     CircuitBreaker,
     DegradationLadder,

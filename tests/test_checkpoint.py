@@ -174,3 +174,54 @@ def test_all_recent_corrupt_walks_to_oldest(tmp_path):
     step, restored = restore_latest(d, TEMPLATE)
     assert step == 100
     np.testing.assert_allclose(restored["w"], np.asarray(tree_a()["w"]))
+
+
+# ---------------------------------------------------------------------------
+# roundtrip, keep-k, torn and corrupt saves on a nested tree
+# ---------------------------------------------------------------------------
+
+def _tree():
+    return {"a": jnp.arange(6.0).reshape(2, 3),
+            "nested": {"b": jnp.ones(4, jnp.int32)},
+            "lst": [jnp.zeros(2), jnp.full(3, 7.0)]}
+
+
+def test_checkpoint_roundtrip(tmp_path):
+    t = _tree()
+    save_checkpoint(str(tmp_path), 7, t)
+    step, restored = restore_latest(str(tmp_path), jax.tree.map(jnp.zeros_like, t))
+    assert step == 7
+    for a, b in zip(jax.tree.leaves(t), jax.tree.leaves(restored)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_checkpoint_keep_k_and_torn_save(tmp_path):
+    t = _tree()
+    for s in (1, 2, 3, 4):
+        save_checkpoint(str(tmp_path), s, t, keep=2)
+    assert list_steps(str(tmp_path)) == [3, 4]
+    # simulate a torn save: .tmp dir + corrupt latest
+    os.makedirs(tmp_path / "step_000000009.tmp")
+    os.makedirs(tmp_path / "step_000000005")  # no manifest → invalid
+    step, _ = restore_latest(str(tmp_path), t)
+    assert step == 4  # falls back past the invalid one
+
+
+def test_checkpoint_corrupt_arrays_fall_back(tmp_path):
+    t = _tree()
+    save_checkpoint(str(tmp_path), 1, t)
+    save_checkpoint(str(tmp_path), 2, t, keep=5)
+    # corrupt step 2's array file
+    with open(tmp_path / "step_000000002" / "arrays.npz", "wb") as f:
+        f.write(b"garbage")
+    step, restored = restore_latest(str(tmp_path), t)
+    assert step == 1
+
+
+def test_checkpoint_manager_async(tmp_path):
+    mgr = CheckpointManager(str(tmp_path), every=2, keep=2, async_save=True)
+    t = _tree()
+    assert not mgr.maybe_save(1, t)
+    assert mgr.maybe_save(2, t)
+    mgr.wait()
+    assert list_steps(str(tmp_path)) == [2]

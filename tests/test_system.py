@@ -90,3 +90,40 @@ def test_nsw_baseline(corpus):
     g = baselines.build_nsw(base, max_degree=24, ef=48, wave=256)
     res = greedy_search(g, jnp.asarray(queries), k=10, l=64)
     assert recall_at_k(res.ids, gt_i, 10) > 0.45
+
+
+def test_clustered_vectors_shape_and_spread():
+    X = clustered_vectors(500, 16, 10, seed=1)
+    assert X.shape == (500, 16) and np.isfinite(X).all()
+    assert X.std() > 0.5
+
+
+def test_ann_server_batching(small_corpus):
+    from repro.core import BuildParams, SearchParams, build_approx
+    from repro.serve import AnnServer
+
+    g = build_approx(small_corpus["base"],
+                     BuildParams(max_degree=16, beam_width=32, t=8, iters=1))
+    srv = AnnServer(g, SearchParams(k=5, l0=8, l_max=32, adaptive=False,
+                                    max_hops=256), max_batch=16,
+                    buckets=(4, 16))
+    srv.submit_many(small_corpus["queries"][:23])
+    out = srv.drain()
+    assert len(out) == 23
+    assert srv.stats.n_batches == 2
+    ids0, d0 = out[0]
+    assert ids0.shape == (5,) and (np.diff(d0) >= -1e-5).all()
+
+
+def test_ann_smoke_config():
+    """The paper's own (sift1m) smoke config builds + serves end to end."""
+    from repro.configs import sift1m
+
+    sc = sift1m.SMOKE
+    X = clustered_vectors(sc["n"], sc["dim"], 32, seed=0)
+    idx = build_emqg(X, sc["build"])
+    res = error_bounded_probing_search(
+        idx, jnp.asarray(X[:16] + 0.01), k=sc["search"].k,
+        alpha=sc["search"].alpha, l_max=sc["search"].l_max)
+    assert res.ids.shape == (16, sc["search"].k)
+    assert bool(jnp.all(jnp.isfinite(res.dists)))
